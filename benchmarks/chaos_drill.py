@@ -280,6 +280,10 @@ def main():
     ap.add_argument("--keep", action="store_true",
                     help="keep the scratch dir (worker logs, stores)")
     args = ap.parse_args()
+    from repro.service.workers import require_cpu_backend
+
+    require_cpu_backend(
+        "benchmarks/chaos_drill.py", "run it with JAX_PLATFORMS=cpu")
     out_path = os.path.abspath(args.out or os.path.join(
         os.path.dirname(__file__), "..", "BENCH_chaos.json"))
     root = tempfile.mkdtemp(prefix="chaos_drill_")

@@ -683,6 +683,10 @@ def main():
     ap.add_argument("--rounds", type=int, default=None)
     ap.add_argument("--out", default=None)
     args = ap.parse_args()
+    from repro.service.workers import require_cpu_backend
+
+    require_cpu_backend(
+        "benchmarks/labeler_throughput.py", "run it with JAX_PLATFORMS=cpu")
     root = os.path.join(os.path.dirname(__file__), "..")
     args.out = args.out or os.path.join(
         root, "BENCH_obs.json" if args.obs

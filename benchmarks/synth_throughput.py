@@ -222,6 +222,10 @@ def main():
     ap.add_argument("--out", default=os.path.join(
         os.path.dirname(__file__), "..", "BENCH_synth.json"))
     args = ap.parse_args()
+    from repro.service.workers import require_cpu_backend
+
+    require_cpu_backend(
+        "benchmarks/synth_throughput.py", "run it with JAX_PLATFORMS=cpu")
 
     from repro.core.acl.library import default_library
     from repro.core.features import synth

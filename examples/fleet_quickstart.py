@@ -52,6 +52,10 @@ def wait_for(pred, timeout, what):
 
 
 def main():
+    from repro.service.workers import require_cpu_backend
+
+    require_cpu_backend(
+        "examples/fleet_quickstart.py", "run it with JAX_PLATFORMS=cpu")
     from repro.fleet import serve_fleet
 
     store_path = os.path.join(tempfile.mkdtemp(prefix="fleet_demo_"),

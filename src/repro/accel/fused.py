@@ -26,7 +26,8 @@ Design constraints, in priority order:
   On top of the static proofs, the PR-5 verification scheme applies
   dynamically: each plan's first calls ALSO run the numpy engine and
   compare; a divergent accelerator family is pinned back to numpy for
-  the process lifetime.
+  the process lifetime.  An exception raised while compiling or running
+  a fused program is not a divergence: it propagates to the caller.
 
 * **Zero steady-state recompiles.**  Population sizes are bucketed (pad
   G up to a power of two with repeats of the first genome, slice the
@@ -38,8 +39,9 @@ Design constraints, in priority order:
 
 * **Observability + kill switch.**  ``REPRO_SIM_FUSED=0`` falls back to
   the numpy engine wholesale; compiles / bucket hits / verify calls /
-  pins are counted (``stats()``, mirrored into ``repro.obs`` counters)
-  and every device execution runs under a ``sim.fused`` span.
+  pins / fallbacks (a fusible family that ran numpy) are counted
+  (``stats()``, mirrored into ``repro.obs`` counters) and every device
+  execution runs under a ``sim.fused`` span.
 """
 
 from __future__ import annotations
@@ -290,36 +292,16 @@ class _Engine:
         return dev
 
     def gather(self, lut_dev, genes, cols, *, per_genome: bool):
-        """Traceable population LUT gather (Pallas on TPU, XLA gather
-        elsewhere — on CPU an interpreted Pallas round-trip would cost
-        more than the gather saves)."""
+        """Traceable population LUT gather, the XLA form on every
+        backend: it fuses into the surrounding program, and the TPU
+        compiler refuses the Pallas kernel in ``kernels.population_lut``
+        (Mosaic lowers only 2-D gathers)."""
         from ..kernels.population_lut import gather_xla
-        from ..kernels.population_lut.ops import on_tpu
 
-        S = lut_dev.shape[1]
-        if on_tpu():
-            return self._gather_pallas(lut_dev, genes, cols, per_genome)
         return gather_xla(
-            lut_dev.reshape(-1), genes, cols, nslots=S, per_genome=per_genome
+            lut_dev.reshape(-1), genes, cols, nslots=lut_dev.shape[1],
+            per_genome=per_genome,
         )
-
-    def _gather_pallas(self, lut_dev, genes, cols, per_genome: bool):
-        import jax.numpy as jnp
-
-        from ..kernels.population_lut import population_lut_gather_pallas
-
-        S = lut_dev.shape[1]
-        M = cols.shape[-2]
-        bm = 256
-        pad = (-M) % bm
-        if pad:
-            width = [(0, 0)] * (cols.ndim - 2) + [(0, pad), (0, 0)]
-            cols = jnp.pad(cols, width)
-        out = population_lut_gather_pallas(
-            lut_dev, genes, cols, per_genome=per_genome,
-            bg=genes.shape[0], bm=min(bm, M + pad),
-        )
-        return out[:, :M] if pad else out
 
     def select_add(self, gene_col, a, b, *, signed: bool):
         """All-circuits adder stack + per-genome selection.  ``a``/``b``:
@@ -444,7 +426,7 @@ def _plan_for(accel, library: Library) -> Optional[FusedPlan]:
         if eng is not None:
             try:
                 plan = builder(accel, library, eng)
-            except Exception:
+            except OverflowError:  # a LUT exceeds int32: unfusible
                 log.exception("fused sim: plan build failed for %s", accel.name)
                 plan = None
     if plan is not None:
@@ -539,8 +521,8 @@ def _compiled(plan: FusedPlan, *, bucket: int, per_genome: bool,
 def _execute(plan: FusedPlan, genomes: np.ndarray, x: np.ndarray,
              *, per_genome: bool, ref: Optional[np.ndarray] = None):
     """Bucket, pad, run the compiled program, slice back to G."""
+    import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
 
     from .. import obs
 
@@ -557,7 +539,7 @@ def _execute(plan: FusedPlan, genomes: np.ndarray, x: np.ndarray,
     with obs.span("sim.fused", g=G, bucket=B, sse=bool(want_sse)):
         # x64 at trace AND call time: the jax jit cache keys on the flag,
         # and the SSE tail accumulates exact int64
-        with enable_x64():
+        with jax.enable_x64(True):
             args = [jnp.asarray(g_pad), jnp.asarray(x_np)]
             if want_sse:
                 args.append(jnp.asarray(ref))
@@ -609,6 +591,10 @@ def _gate(accel, library) -> Optional[FusedPlan]:
         return None
     plan = _plan_for(accel, library)
     if plan is None or plan.key in _PINNED:
+        if _builder_for(accel) is not None:
+            # a fusible family runs the numpy engine: count it, so a
+            # run that expects the device path can check it got it
+            _bump("fallback_calls")
         return None
     return plan
 
@@ -624,14 +610,8 @@ def try_simulate_batch(
     if plan is None:
         return None
     genomes = np.atleast_2d(np.asarray(genomes))
-    try:
-        raw = _execute(plan, genomes, inputs, per_genome=per_genome_inputs)
-        out = plan.post(raw, inputs, per_genome_inputs)
-    except Exception:
-        log.exception("fused sim failed for %s — pinning", accel.name)
-        _PINNED[plan.key] = "error"
-        _bump("pins")
-        return None
+    raw = _execute(plan, genomes, inputs, per_genome=per_genome_inputs)
+    out = plan.post(raw, inputs, per_genome_inputs)
     left = _VERIFY_LEFT.get(plan.key, _verify_budget())
     if left > 0:
         want = _numpy_reference(
@@ -658,21 +638,12 @@ def try_qor_batch(
     if plan is None or plan.qor_ref is None:
         return None
     genomes = np.atleast_2d(np.asarray(genomes))
-    try:
-        ref = plan.qor_ref(accel, inputs)
-        if peak is None:
-            pk = float(np.max(np.abs(ref))) or 1.0
-        else:
-            pk = float(peak)
-        sse = _execute(plan, genomes, inputs, per_genome=False, ref=ref)
-        from ..core.qor import psnr_from_sse
+    ref = plan.qor_ref(accel, inputs)
+    pk = (float(np.max(np.abs(ref))) or 1.0) if peak is None else float(peak)
+    sse = _execute(plan, genomes, inputs, per_genome=False, ref=ref)
+    from ..core.qor import psnr_from_sse
 
-        vals = psnr_from_sse(sse, ref.size, pk)
-    except Exception:
-        log.exception("fused qor failed for %s — pinning", accel.name)
-        _PINNED[plan.key] = "error"
-        _bump("pins")
-        return None
+    vals = psnr_from_sse(sse, ref.size, pk)
     left = _VERIFY_LEFT.get(plan.key, _verify_budget())
     if left > 0:
         want = _numpy_reference(
@@ -684,12 +655,6 @@ def try_qor_batch(
         _VERIFY_LEFT[plan.key] = left - 1
     _bump("fused_qor_calls")
     return vals
-
-
-def note_fallback() -> None:
-    """Callers that consciously took the numpy path report it here so
-    the fused/fallback ratio is observable."""
-    _bump("fallback_calls")
 
 
 def _register_staged() -> None:

@@ -44,6 +44,12 @@ HEVC_C = np.array(
 
 _SHIFT1 = 8  # stage-1 renormalization to stay in the signed 8-bit domain
 
+# C's rows are orthogonal, so C^-1 = C^T diag(1 / |row|^2): each entry is
+# one correctly rounded division, the same bits on every host
+_ROW_NORMS = (HEVC_C * HEVC_C).sum(axis=1)
+assert np.array_equal(HEVC_C @ HEVC_C.T, np.diag(_ROW_NORMS))
+_CINV = HEVC_C.T.astype(np.float64) / _ROW_NORMS
+
 
 def signed16(fn: Callable) -> Callable:
     """Lift an unsigned 16-bit adder model to signed two's complement:
@@ -232,10 +238,23 @@ class HEVCDct(Accelerator):
         return y
 
     def _reconstruct(self, coeffs: np.ndarray) -> np.ndarray:
-        """Exact float inverse of the renormalized forward transform."""
-        cinv = np.linalg.inv(HEVC_C.astype(np.float64))
-        # forward was  Y ~= (C X C^T) / 2^8  (stage-1 shift); invert:
-        x = cinv @ (coeffs.astype(np.float64) * (1 << _SHIFT1)) @ cinv.T
+        """Exact float inverse of the renormalized forward transform.
+
+        The forward was ``Y ~= (C X C^T) / 2^8`` (stage-1 shift), so
+        ``X = C^-1 (Y 2^8) C^-T``, summed term by term in a fixed order
+        with elementwise ops.  A BLAS matmul is not used: its rounding
+        may change from call to call on one host (the kernel it picks
+        can follow the buffers' alignment), and the fused engine's
+        output is verified bit for bit against this tail."""
+        y = coeffs.astype(np.float64) * (1 << _SHIFT1)
+        # t[..., i, l] = sum_k C^-1[i, k] y[..., k, l]
+        t = _CINV[:, 0, None] * y[..., None, 0, :]
+        for k in range(1, 4):
+            t = t + _CINV[:, k, None] * y[..., None, k, :]
+        # x[..., i, j] = sum_l t[..., i, l] C^-1[j, l]
+        x = t[..., :, 0, None] * _CINV[:, 0]
+        for l in range(1, 4):
+            x = x + t[..., :, l, None] * _CINV[:, l]
         return x
 
     def simulate(self, circuits: Sequence[Circuit], inputs: np.ndarray) -> np.ndarray:
@@ -427,8 +446,9 @@ def _hevc_fused_plan(accel, library, eng):
     passes, renorm/clip between.  The device returns the INTEGER
     coefficients; the float64 inverse-transform tail stays on the host
     (``_reconstruct``) because float64 matmul contraction order — and
-    hence bits — is BLAS/XLA-implementation-defined, while the host
-    path is shared with the numpy engine verbatim."""
+    hence bits — is XLA-implementation-defined, while the host tail
+    (fixed-order elementwise sums, no BLAS) is shared with the numpy
+    engine verbatim."""
     import jax.numpy as jnp
 
     luts = [eng.lut("mul8s", HEVC_C[r], tag=f"mcm{r}") for r in range(4)]

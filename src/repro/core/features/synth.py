@@ -45,6 +45,7 @@ __all__ = [
     "set_shared_synth_cache",
     "synth_stats",
     "reset_fast_codegen",
+    "backend_tag",
     "LABEL_KEYS",
     "DEFAULT_QOR_SEED",
     "SYNTH_AC_DIM",
@@ -79,7 +80,9 @@ class SynthResult(dict):
 # into HLO-level cost (e.g. the LM forward under the non-thunk runtime)
 # are pinned to default codegen, keeping labels byte-identical to the
 # seed engine by construction.  REPRO_SYNTH_FAST=0 disables the whole
-# mechanism; unknown options degrade to a default compile.
+# mechanism; unknown options degrade to a default compile.  The options
+# are CPU-LLVM flags, so the mechanism runs only on the CPU backend;
+# elsewhere each graph compiles once with default options.
 FAST_CODEGEN = os.environ.get("REPRO_SYNTH_FAST", "1") != "0"
 _FAST_COMPILER_OPTIONS = {
     "xla_backend_optimization_level": 0,
@@ -134,19 +137,27 @@ _STRUCT_VERIFY_SAMPLES = 2
 # (service/workers.py) instead.
 COMPILE_WORKERS = int(os.environ.get("REPRO_SYNTH_COMPILE_WORKERS", "1") or 1)
 
-# cache-key salt: a jax/jaxlib upgrade or a label-semantics change must
-# MISS a persisted compile cache instead of serving stale cost numbers
+# cache-key salt: a jax/jaxlib upgrade, another backend or a label-
+# semantics change must MISS a persisted compile cache instead of
+# serving stale cost numbers
 SYNTH_CACHE_SCHEMA_VERSION = 1
 
 
-def _cache_salt() -> str:
-    try:
-        import jax
+def backend_tag() -> str:
+    """The backend a label is computed on: JAX's default backend and its
+    first device's kind (e.g. ``tpu:TPU v5 lite``).  Compile costs come
+    from that backend's compiler, so labels and compile caches key on it:
+    a store warmed on one backend misses on another."""
+    import jax
 
-        jv = jax.__version__
-    except Exception:  # noqa: BLE001 - digests still stable without jax
-        jv = "nojax"
-    return f"v{SYNTH_CACHE_SCHEMA_VERSION}|jax{jv}"
+    return f"{jax.default_backend()}:{jax.devices()[0].device_kind}"
+
+
+def _cache_salt() -> str:
+    import jax
+
+    return (f"v{SYNTH_CACHE_SCHEMA_VERSION}|jax{jax.__version__}|"
+            f"{backend_tag()}")
 
 
 def _digest(tag: str, payload: object) -> str:
@@ -638,6 +649,7 @@ def set_shared_synth_cache(cache: SynthCache) -> SynthCache:
 def synth_stats() -> Dict[str, object]:
     """Process-wide synthesis engine counters (for ``GET /stats``)."""
     return {
+        "backend": backend_tag(),
         "structural_keys": STRUCTURAL_KEYS,
         "fast_codegen": FAST_CODEGEN,
         "compile_workers": COMPILE_WORKERS,
@@ -659,16 +671,34 @@ def _cost_numbers(compiled) -> Dict[str, float]:
     from ...dist.compat import compiled_cost_analysis
 
     ca = compiled_cost_analysis(compiled)
-    return {k: float(ca.get(k, 0.0)) for k in _COST_KEYS}
+    missing = [k for k in _COST_KEYS if k not in ca]
+    if missing:
+        raise RuntimeError(f"compiled cost analysis lacks {missing}: "
+                           f"{sorted(ca)}")
+    return {k: float(ca[k]) for k in _COST_KEYS}
 
 
 def _compile_cost(fn, args, *, fast_key: Optional[str] = None) -> Dict[str, float]:
+    # wall_time is a compile measurement, and an executable loaded from
+    # JAX's persistent compile cache would time the load instead: no
+    # synthesis graph is written there, so none is read back
+    from jax._src import config as jax_config
+
+    with jax_config.persistent_cache_min_compile_time_secs(float("inf")):
+        return _compile_cost_uncached(fn, args, fast_key=fast_key)
+
+
+def _compile_cost_uncached(fn, args, *,
+                           fast_key: Optional[str]) -> Dict[str, float]:
     import jax
 
     t0 = time.perf_counter()
     lowered = jax.jit(fn).lower(*args)
     compiled = None
-    if FAST_CODEGEN and fast_key is not None:
+    # the fast options are CPU-LLVM flags: on any other backend the
+    # graph compiles once, plainly, and a compile error propagates
+    cpu = jax.default_backend() == "cpu"
+    if FAST_CODEGEN and fast_key is not None and cpu:
         verdict = _FAST_VERDICT.get(fast_key, _FAST_VERIFY_SAMPLES)
         if verdict is not False and verdict > 0:
             # verification compile: both ways, compare what labels read

@@ -70,7 +70,7 @@ def sse_batch_jax(ref, outs):
     pairwise float64 sum of exactly-representable integers below 2^53 is
     association-independent, i.e. also the exact integer sum.  Requires
     x64 to be enabled at trace time (the fused engine traces under
-    ``jax.experimental.enable_x64``)."""
+    ``jax.enable_x64``)."""
     import jax.numpy as jnp
 
     d = outs.astype(jnp.int64) - ref.astype(jnp.int64)[None]

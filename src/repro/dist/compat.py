@@ -1,89 +1,32 @@
-"""jax version compatibility for mesh construction and mesh contexts.
-
-The launch/dryrun drivers (and the distributed tests) target the newer
-explicit-mesh API (``jax.make_mesh(..., axis_types=...)`` +
-``jax.set_mesh``).  Older jax (<= 0.4.x, what this container ships)
-predates ``AxisType``/``set_mesh``; there the legacy ``with mesh:``
-context provides the ambient mesh that ``dist.sharding.constrain``
-reads.  Everything mesh-shaped in this repo goes through these two
-helpers instead of calling jax directly."""
+"""Mesh construction, the ambient-mesh context and compiled cost analysis
+on the installed jax.  Everything mesh-shaped in this repo goes through
+these helpers instead of calling jax directly."""
 
 from __future__ import annotations
 
 from typing import Sequence, Tuple
 
-__all__ = ["make_mesh", "mesh_context", "compiled_cost_analysis", "opt_barrier"]
+__all__ = ["make_mesh", "mesh_context", "compiled_cost_analysis"]
 
 
 def make_mesh(shape: Tuple[int, ...], axes: Sequence[str]):
-    """``jax.make_mesh`` with Auto axis types where supported."""
+    """``jax.make_mesh`` with Auto axis types."""
     import jax
 
     axes = tuple(axes)
-    try:
-        return jax.make_mesh(
-            shape, axes,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
-        )
-    except (AttributeError, TypeError):
-        pass
-    if hasattr(jax, "make_mesh"):  # >= 0.4.35, no AxisType yet
-        return jax.make_mesh(shape, axes)
-    from jax.experimental import mesh_utils  # pre-make_mesh versions
-
-    devices = mesh_utils.create_device_mesh(shape)
-    return jax.sharding.Mesh(devices, axes)
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+    )
 
 
 def mesh_context(mesh):
-    """Context manager making ``mesh`` ambient: ``jax.set_mesh`` on new
-    jax, ``jax.sharding.use_mesh`` on the transitional releases that
-    shipped it first, the legacy ``with mesh:`` resource context
-    otherwise."""
+    """Context manager making ``mesh`` ambient (``jax.set_mesh``)."""
     import jax
 
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    if hasattr(jax.sharding, "use_mesh"):
-        return jax.sharding.use_mesh(mesh)
-    return mesh  # Mesh is itself a context manager on older jax
+    return jax.set_mesh(mesh)
 
 
 def compiled_cost_analysis(compiled) -> dict:
-    """``compiled.cost_analysis()`` as a flat dict.  Older jax returns a
-    one-element list of dicts (per computation); newer returns the dict
-    directly."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return dict(ca or {})
-
-
-def opt_barrier(tree):
-    """``jax.lax.optimization_barrier`` that is differentiable on every
-    jax version (older jax has no VJP rule for the barrier primitive —
-    wrap it in a custom VJP that barriers the cotangent too)."""
-    import jax
-
-    return _build_barrier(jax)(tree)
-
-
-def _build_barrier(jax):
-    global _BARRIER
-    if _BARRIER is None:
-        @jax.custom_vjp
-        def barrier(tree):
-            return jax.lax.optimization_barrier(tree)
-
-        def fwd(tree):
-            return jax.lax.optimization_barrier(tree), None
-
-        def bwd(_res, g):
-            return (jax.lax.optimization_barrier(g),)
-
-        barrier.defvjp(fwd, bwd)
-        _BARRIER = barrier
-    return _BARRIER
-
-
-_BARRIER = None
+    """``compiled.cost_analysis()`` as a dict (empty when the backend
+    reports none)."""
+    return dict(compiled.cost_analysis() or {})

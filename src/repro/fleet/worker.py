@@ -36,7 +36,7 @@ from typing import Dict, Optional
 
 import numpy as np
 
-from .. import faults, obs
+from .. import compile_cache, faults, obs
 from .http import CircuitBreaker, HttpError, request_json
 from .protocol import PROTOCOL_VERSION, build_context, encode_labels
 
@@ -344,6 +344,7 @@ def main(argv=None):
     ap.add_argument("--verbose", action="store_true")
     args = ap.parse_args(argv)
 
+    compile_cache.enable()
     obs.setup_logging(args.log_level
                       or ("info" if args.verbose else "warning"))
     if args.trace:

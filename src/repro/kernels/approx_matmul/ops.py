@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..backend import interpret_mode
 from . import ref
 from .kernel import lut_matmul_pallas, rank_k_mxu
 
@@ -140,15 +141,16 @@ def approx_matmul(
     *,
     path: str = "mxu",     # "mxu" (rank-k deployment) | "lut" (behavioral)
     use_pallas: bool = False,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
     """Approximate x @ w under one circuit spec.
 
     path="mxu": deployment semantics, f32 out.  path="lut": behavioral
-    bit-exact semantics.  use_pallas selects the tiled TPU kernels (CPU
-    validation runs them with interpret=True).
+    bit-exact semantics.  use_pallas selects the tiled TPU kernels;
+    ``interpret`` defaults to interpret mode on the CPU backend only.
     """
     if use_pallas:
+        interpret = interpret_mode(interpret)
         if path == "lut":
             return lut_matmul_pallas(
                 x, w, jnp.asarray(spec.table), signed=spec.signed,

@@ -1,6 +1,6 @@
 """Public attention op: dispatches between the naive reference, the
 chunked scan (production path on any backend) and the Pallas flash kernel
-(TPU target; interpret mode on CPU)."""
+(TPU target; interpret mode by default on the CPU backend only)."""
 
 from __future__ import annotations
 
@@ -8,6 +8,7 @@ from typing import Optional
 
 import jax.numpy as jnp
 
+from ..backend import interpret_mode
 from .kernel import flash_attention_fwd
 from .ref import chunked_attention, mha_reference, repeat_kv
 
@@ -23,7 +24,7 @@ def attention(
     impl: str = "chunked",   # "chunked" | "naive" | "pallas"
     chunk: int = 1024,
     q_offset: int = 0,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
     if impl == "naive":
         return mha_reference(q, k, v, causal=causal, q_offset=q_offset)
@@ -43,7 +44,7 @@ def attention(
             vr.reshape(b * h, -1, d),
             causal=causal,
             q_offset=q_offset,
-            interpret=interpret,
+            interpret=interpret_mode(interpret),
         )
         return out.reshape(b, h, sq, d)
     raise ValueError(f"unknown attention impl {impl!r}")

@@ -1,6 +1,6 @@
-"""Population LUT-gather: the batched behavioral sim's inner gather as a
-tiled Pallas TPU kernel, an XLA gather (the CPU fused-engine path) and a
-numpy reference.
+"""Population LUT-gather: the batched behavioral sim's inner gather as an
+XLA gather (the fused engine's path on every backend), a tiled Pallas
+kernel (interpret mode only) and a numpy reference.
 
 ``out[g, m, s] = lut[genes[g, s], s, cols[m, s]]`` — one gathered
 product per (genome, input element, multiplier slot), the population

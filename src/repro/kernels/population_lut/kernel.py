@@ -7,11 +7,11 @@ every (bg, bm) tile performs one flat gather:
 
     out[g, m, s] = lut[genes[g, s], s, cols[m, s]]
 
-On TPU the gather lowers to VMEM dynamic-slices (same trade as
-``approx_matmul.lut_matmul_pallas``); on CPU the kernel runs under
-``interpret=True`` for validation only — the fused engine's CPU hot path
-uses the plain XLA gather in ``ops.gather_xla``, which fuses into the
-surrounding program.
+The kernel runs only under ``interpret=True``: the TPU compiler (Mosaic)
+refuses the flat gather over a 3-D index ("Only 2D gather is
+supported").  The fused engine's hot path on every backend is the plain
+XLA gather in ``ops.gather_xla``, which fuses into the surrounding
+program.
 """
 
 from __future__ import annotations

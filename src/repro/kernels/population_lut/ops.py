@@ -1,16 +1,15 @@
 """Dispatch layer for the population LUT gather.
 
 ``gather_xla`` is the traceable building block the fused engine inlines
-into its per-accelerator XLA programs (CPU and TPU alike — on CPU a
-Pallas interpret round-trip would cost more than the gather saves);
-``population_lut_gather`` is the standalone op with backend selection,
-mirroring ``approx_matmul.ops``: real Pallas kernel on TPU, interpret
-mode for validation, numpy reference otherwise.
+into its per-accelerator XLA programs on every backend.
+``population_lut_gather`` is the standalone op with backend selection:
+the XLA gather by default, the numpy reference, or the Pallas kernel
+when asked for by name.  The Pallas kernel only runs in interpret mode:
+the TPU compiler refuses its 3-D gather ("Only 2D gather is
+supported").
 """
 
 from __future__ import annotations
-
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -19,14 +18,7 @@ import numpy as np
 from .kernel import population_lut_gather_pallas
 from .ref import population_lut_gather_ref
 
-__all__ = ["gather_xla", "population_lut_gather", "on_tpu"]
-
-
-def on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+__all__ = ["gather_xla", "population_lut_gather"]
 
 
 def gather_xla(
@@ -37,12 +29,24 @@ def gather_xla(
     nslots: int,
     per_genome: bool = False,
 ) -> jnp.ndarray:
-    """Traceable ``out[g, m, s] = lut[genes[g, s], s, cols[.., m, s]]``
-    as one flat XLA gather; fuses into the surrounding jit."""
-    sidx = jnp.arange(nslots, dtype=jnp.int32)[None, None, :]
-    base = (genes[:, None, :] * nslots + sidx) * 256
-    idx = base + (cols if per_genome else cols[None])
-    return jnp.take(flat_lut, idx.reshape(-1), axis=0).reshape(idx.shape)
+    """Traceable ``out[g, m, s] = lut[genes[g, s], s, cols[.., m, s]]``;
+    fuses into the surrounding jit.
+
+    Two small gathers: each genome's per-slot tables ``(G, S, 256)``,
+    then a row lookup of the columns in them.  One flat gather with an
+    index per output element computes the same, but the TPU compiler
+    takes minutes over it at G=64 where this form takes about a second.
+    """
+    lut = flat_lut.reshape(-1, nslots, 256)
+    tables = lut[genes, jnp.arange(nslots)[None, :]]          # (G, S, 256)
+    lookup = jax.vmap(lambda t, c: jnp.take(t, c, axis=0))
+    if per_genome:
+        G, M = cols.shape[0], cols.shape[1]
+        rows = lookup(tables.reshape(G * nslots, 256),
+                      cols.transpose(0, 2, 1).reshape(G * nslots, M))
+        return rows.reshape(G, nslots, M).transpose(0, 2, 1)
+    rows = lookup(tables.transpose(1, 2, 0), cols.T)          # (S, M, G)
+    return rows.transpose(2, 1, 0)
 
 
 def population_lut_gather(
@@ -51,13 +55,10 @@ def population_lut_gather(
     cols: np.ndarray,
     *,
     per_genome: bool = False,
-    backend: Optional[str] = None,
+    backend: str = "xla",
 ) -> np.ndarray:
-    """(G, M, S) gathered products; ``backend``: "pallas",
-    "pallas_interpret", "xla", "ref" or None (auto: pallas on TPU, xla
-    elsewhere)."""
-    if backend is None:
-        backend = "pallas" if on_tpu() else "xla"
+    """(G, M, S) gathered products; ``backend``: "xla", "ref",
+    "pallas" or "pallas_interpret"."""
     if backend == "ref":
         return population_lut_gather_ref(lut, genes, cols, per_genome=per_genome)
     lut32 = np.asarray(lut, dtype=np.int32)

@@ -16,7 +16,7 @@ import json
 
 import numpy as np
 
-from .. import obs
+from .. import compile_cache, obs
 from ..core.acl.library import default_library
 from ..hierarchy.search import HierarchicalConfig, run_hierarchical
 from ..service.campaigns import CampaignManager, make_accelerator
@@ -71,6 +71,7 @@ def main():
     ap.add_argument("--out", default=None)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    compile_cache.enable()
 
     if args.trace:
         obs.set_sink(args.trace)

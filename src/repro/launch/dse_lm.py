@@ -24,6 +24,7 @@ import json
 
 import numpy as np
 
+from .. import compile_cache
 from ..accel.lm import LMAccelerator
 from ..configs import get_config
 from ..core.acl.library import default_library
@@ -70,6 +71,7 @@ def main():
     ap.add_argument("--out", default=None)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    compile_cache.enable()
 
     if args.service:
         return _run_on_service(args)

@@ -18,6 +18,7 @@ import argparse
 import jax
 import jax.numpy as jnp
 
+from .. import compile_cache
 from ..configs import get_config
 from ..models import ApproxPolicy, reduced
 from ..models.common import init_tree
@@ -37,9 +38,12 @@ def serve_batch(
     seed: int = 0,
     params=None,
     prompts=None,
+    generator: Generator | None = None,
 ):
     """Greedy-decode `gen` tokens for a batch of (synthetic by default)
-    prompts.  Returns (tokens (b, prompt+gen), tokens/s)."""
+    prompts.  Returns (tokens (b, prompt+gen), tokens/s).  A caller that
+    has already compiled (or inspected) a ``Generator``'s steps passes it
+    as ``generator``; it must have been built for ``cfg`` and ``policy``."""
     key = jax.random.PRNGKey(seed)
     if params is None:
         params = init_tree(param_specs(cfg), key)
@@ -47,7 +51,11 @@ def serve_batch(
         prompts = jax.random.randint(
             key, (batch, prompt_len), 0, cfg.vocab_size)
     prompts = jnp.asarray(prompts, jnp.int32)
-    g = Generator(cfg, policy=policy, attn_chunk=32, scan_chunk=8)
+    if generator is not None and (generator.cfg != cfg
+                                  or generator.policy is not policy):
+        raise ValueError("generator was built for another cfg or policy")
+    g = generator or Generator(cfg, policy=policy, attn_chunk=32,
+                               scan_chunk=8)
     return g.generate(params, prompts, gen, key=key)
 
 
@@ -85,6 +93,7 @@ def main():
     ap.add_argument("--tier", default="balanced",
                     choices=("exact", "balanced", "budget"))
     args = ap.parse_args()
+    compile_cache.enable()
 
     cfg = get_config(args.arch)
     if args.reduced:

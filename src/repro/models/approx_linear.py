@@ -9,8 +9,8 @@ rank): such projections run as int8-quantized rank-k-corrected MXU
 matmuls (kernels/approx_matmul); unmapped classes run exact bf16.
 
 The compiled HLO of an approximated projection contains (1 + rank) MXU
-matmuls plus two 256-entry gathers — exactly the cost model the paper's
-surrogates learn (DESIGN.md §2).
+matmuls plus two 256-entry gathers per rank — exactly the cost model the
+paper's surrogates learn (DESIGN.md §2).
 """
 
 from __future__ import annotations
@@ -86,18 +86,15 @@ def _approx_matmul_nd(x: jnp.ndarray, w: jnp.ndarray, spec) -> jnp.ndarray:
     xi = qx + 128
     wi = qw + 128
     out = qx.astype(jnp.float32) @ qw.astype(jnp.float32)
-    if spec.rank:
-        u = jnp.asarray(spec.u)
-        v = jnp.asarray(spec.v)
-        ux = jnp.take(u, xi, axis=0)          # (m, k, r)
-        vw = jnp.take(v, wi, axis=0)          # (k, n, r)
-        m, n, r = x2.shape[0], w.shape[1], spec.rank
-        out = out + jnp.einsum(
-            "mkr,knr->mn",
-            ux,
-            vw,
-            preferred_element_type=jnp.float32,
-        )
+    # one 256-entry table per rank: a gathered (k, n, r) stack with the
+    # rank as its minor axis is padded to the TPU's 128 lanes (at rank 2
+    # on a 4096x14336 projection, 14 GB instead of 0.22 GB)
+    u = jnp.asarray(spec.u)
+    v = jnp.asarray(spec.v)
+    for j in range(spec.rank):
+        ux = jnp.take(u[:, j], xi, axis=0)    # (m, k)
+        vw = jnp.take(v[:, j], wi, axis=0)    # (k, n)
+        out = out + jnp.matmul(ux, vw, preferred_element_type=jnp.float32)
     out = out * (sx * sw)
     return out.reshape(*lead, w.shape[1])
 

@@ -238,9 +238,7 @@ def _scan_blocks(params, cfg, x, inv_freq, *, policy, causal, caches, pos,
         # the CPU backend's bf16->f32 dot upcast) out of the loop, which
         # would materialize f32 copies of the ENTIRE stacked stack at
         # once (observed +20 GB on the 398B config)
-        from ..dist.compat import opt_barrier
-
-        blk = opt_barrier(blk)
+        blk = jax.lax.optimization_barrier(blk)
         if remat:
             blk = jax.tree.map(
                 lambda t, s: constrain_cotangent(t, s.logical),

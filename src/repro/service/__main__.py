@@ -8,13 +8,13 @@ from __future__ import annotations
 
 import argparse
 
-from .. import obs
+from .. import compile_cache, obs
 from .api import serve
 from .campaigns import CampaignManager
 from .store import open_label_store
 
 
-def main(argv=None):
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser(
         prog="python -m repro.service",
         description="Pareto-as-a-service: concurrent DSE campaigns with a "
@@ -82,15 +82,14 @@ def main(argv=None):
                          "Perfetto-loadable trace with 'python -m "
                          "repro.obs.export PATH --chrome-trace'")
     ap.add_argument("--verbose", action="store_true")
-    args = ap.parse_args(argv)
+    return ap
 
-    obs.setup_logging(args.log_level
-                      or ("debug" if args.verbose else "info"))
+
+def build_manager(args) -> CampaignManager:
+    """The service's CampaignManager from parsed ``build_parser`` args:
+    the label store (migrated to its path's format), the evaluation
+    backend and the persistent synth cache."""
     log = obs.get_logger("repro.service")
-    if args.trace:
-        obs.set_sink(args.trace)
-        log.info("tracing to %s", args.trace)
-
     store = open_label_store(args.store, migrate=True)
     log.info("label store %s: %d entries", args.store, len(store))
     manager = CampaignManager(
@@ -113,6 +112,21 @@ def main(argv=None):
     if manager.synth_cache is not None:
         log.info("synth cache %s: %d compiled structures",
                  args.synth_cache, len(manager.synth_cache))
+    return manager
+
+
+def main(argv=None):
+    args = build_parser().parse_args(argv)
+    compile_cache.enable()
+
+    obs.setup_logging(args.log_level
+                      or ("debug" if args.verbose else "info"))
+    log = obs.get_logger("repro.service")
+    if args.trace:
+        obs.set_sink(args.trace)
+        log.info("tracing to %s", args.trace)
+
+    manager = build_manager(args)
     if args.snapshots:
         resumable = manager.snapshot_ids()
         if resumable:

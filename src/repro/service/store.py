@@ -131,6 +131,7 @@ class EvalContext:
                 _library_fingerprint(self.library),
                 f"rank_genes={int(self.rank_genes)}",
                 f"qor={self.n_qor_samples}@{self.qor_seed}",
+                f"backend={synth.backend_tag()}",
             ])
             self._fp = hashlib.sha256(sig.encode()).hexdigest()[:24]
         return self._fp

@@ -38,7 +38,10 @@ import numpy as np
 from .. import obs
 from .store import LABEL_KEYS, EvalContext
 
-__all__ = ["ProcessPoolLabeler", "WORKER_XLA_FLAGS", "warm_library"]
+__all__ = [
+    "ProcessPoolLabeler", "WORKER_XLA_FLAGS", "require_cpu_backend",
+    "warm_library",
+]
 
 # Appended to XLA_FLAGS in each worker BEFORE jax loads: one compile's
 # parallel LLVM codegen would fight the other workers for cores, so each
@@ -49,6 +52,22 @@ WORKER_XLA_FLAGS = "--xla_cpu_parallel_codegen_split_count=1"
 
 # per-worker-process state: the warm library and the contexts built so far
 _WORKER_STATE: Dict = {}
+
+
+def require_cpu_backend(what: str, remedy: str) -> None:
+    """Refuse to start JAX worker processes next to this one unless JAX
+    runs on the CPU here.  An accelerator belongs to one process at a
+    time: once this process has touched JAX it holds the chip, and a
+    child that needs it fails or hangs on the lock."""
+    import jax
+
+    backend = jax.default_backend()
+    if backend != "cpu":
+        raise RuntimeError(
+            f"{what} starts worker processes that each load JAX, but this "
+            f"process holds the {backend} backend and a chip belongs to "
+            f"one process at a time; {remedy}"
+        )
 
 
 def warm_library(lib) -> None:
@@ -175,6 +194,10 @@ class ProcessPoolLabeler:
         xla_flags: str = WORKER_XLA_FLAGS,
         synth_cache_path: Optional[str] = None,
     ):
+        require_cpu_backend(
+            "the process labeler",
+            "label in-process with --eval-backend thread",
+        )
         self.n_workers = max(1, int(n_workers))
         self.chunk_size = None if chunk_size is None else max(1, int(chunk_size))
         self.synth_cache_path = synth_cache_path

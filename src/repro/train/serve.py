@@ -93,10 +93,10 @@ class Generator:
                  attn_chunk: int = 1024, scan_chunk: int = 128):
         self.cfg = cfg
         self.policy = policy
-        self._prefill = jax.jit(make_prefill_step(
+        self.prefill = jax.jit(make_prefill_step(
             cfg, policy=policy, attn_chunk=attn_chunk,
             scan_chunk=scan_chunk))
-        self._decode = jax.jit(make_decode_step(cfg, policy=policy))
+        self.decode = jax.jit(make_decode_step(cfg, policy=policy))
 
     def generate(
         self,
@@ -135,7 +135,7 @@ class Generator:
                 key, (batch, cfg.frontend_len, cfg.d_model),
                 jnp.float32) * 0.1
 
-        out = self._prefill(params, batch_in, caches)
+        out = self.prefill(params, batch_in, caches)
         enc_out = None
         if cfg.is_encoder_decoder:
             logits, caches, enc_out = out
@@ -145,12 +145,16 @@ class Generator:
 
         toks = [prompts, nxt]
         pos0 = prompt_len + vis
+        # the decode clock covers the device's work and nothing else:
+        # prefill has finished when it starts, the last token when it stops
+        nxt.block_until_ready()
         t0 = time.perf_counter()
         for i in range(int(gen) - 1):
-            nxt, logits, caches = self._decode(
+            nxt, logits, caches = self.decode(
                 params, caches, nxt, jnp.int32(pos0 + i), enc_out=enc_out
             )
             toks.append(nxt)
+        nxt.block_until_ready()
         dt = time.perf_counter() - t0
         tokens = jnp.concatenate(toks, axis=1)
         tps = batch * (int(gen) - 1) / max(dt, 1e-9)

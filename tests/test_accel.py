@@ -5,6 +5,7 @@ import numpy as np
 import pytest
 
 from repro.accel import GaussianFilter, HEVCDct, MCMAccelerator
+from repro.accel.hevc_dct import HEVC_C, _blocks
 from repro.accel.approxfpgas import circuit_level_front, restricted_library
 from repro.core.acl.library import default_library
 
@@ -64,6 +65,26 @@ def test_hevc_exact_roundtrip():
     inp = h.sample_inputs(1, seed=2)
     circuits, _ = h.decode(h.exact_genome(LIB), LIB)
     assert h.qor(circuits, inp) >= 40.0  # renorm shift loses some precision
+
+
+def test_hevc_reconstruct_same_bits_for_any_buffer_layout():
+    """The float inverse-transform tail gives the same bits whatever the
+    coefficient buffer's alignment or order (the fused engine is checked
+    bit for bit against it), and inverts the forward transform."""
+    h = HEVCDct()
+    x = _blocks(h.sample_inputs(2, seed=3))
+    exact = [([lambda a, b: a * b] * 4, [lambda a, b: a + b] * 3)] * 4
+    coeffs = h._transform(x, exact)
+    want = h._reconstruct(coeffs)
+    raw = np.empty(coeffs.nbytes + 8, np.uint8)  # 8 bytes off alignment
+    shifted = np.frombuffer(raw[8:].data, np.int64).reshape(coeffs.shape)
+    shifted[...] = coeffs
+    for c in (shifted, np.asfortranarray(coeffs), coeffs.copy()):
+        assert np.array_equal(h._reconstruct(c), want)
+    cinv = np.linalg.inv(HEVC_C.astype(np.float64))
+    assert np.allclose(want, cinv @ (coeffs * 256.0) @ cinv.T,
+                       rtol=0, atol=1e-12)
+    assert np.max(np.abs(want - x)) < 2.0  # the >>8 renorm's rounding
 
 
 def test_hevc_genome_has_28_slots():

@@ -295,3 +295,16 @@ def test_process_pool_can_label_gates_contexts():
                                           n_qor_samples=2))
     # verdicts are cached per fingerprint
     assert len(pool._safe_fps) == 2
+
+
+def test_process_pool_refuses_an_accelerator_backend(monkeypatch):
+    """On a chip the parent already holds the device, so the process
+    backend must refuse at construction (naming the thread backend)
+    instead of spawning children that hang on the device lock."""
+    import jax
+
+    from repro.service.workers import ProcessPoolLabeler
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="--eval-backend thread"):
+        ProcessPoolLabeler(1)

@@ -253,6 +253,44 @@ def test_context_fingerprint_sensitivity(ctx):
     assert EvalContext(MCMAccelerator(1), sub, n_qor_samples=2).fingerprint != base
 
 
+def test_backend_rekeys_labels_and_compile_cache(monkeypatch):
+    """A store or compile cache warmed on one backend must miss on
+    another: the backend and device kind are part of both keys."""
+    from repro.core.features import synth
+
+    lib = default_library()
+    genome = np.zeros(len(MCMAccelerator(1).slots), dtype=np.int64)
+    cpu = EvalContext(MCMAccelerator(1), lib, n_qor_samples=2)
+    cpu_key, cpu_digest = cpu.key(genome), synth._digest("t", "payload")
+    assert synth.backend_tag().startswith("cpu:")
+    monkeypatch.setattr(synth, "backend_tag", lambda: "tpu:TPU v5 lite")
+    tpu = EvalContext(MCMAccelerator(1), lib, n_qor_samples=2)
+    assert tpu.fingerprint != cpu.fingerprint
+    assert tpu.key(genome) != cpu_key
+    assert synth._digest("t", "payload") != cpu_digest
+
+
+def test_compile_cache_dir_from_env_else_fixed_in_checkout(monkeypatch):
+    """Entry points leave a set JAX_COMPILATION_CACHE_DIR to JAX and
+    otherwise use one fixed directory in the checkout."""
+    import jax
+
+    from repro import compile_cache
+
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "some/cache/dir")
+        assert compile_cache.enable() == "some/cache/dir"
+        assert jax.config.jax_compilation_cache_dir == prev
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        root = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
+        want = os.path.join(root, ".jax_cache")
+        assert compile_cache.enable() == want
+        assert jax.config.jax_compilation_cache_dir == want
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
+
+
 # ---------------------------------------------------------------------------
 # scheduler
 # ---------------------------------------------------------------------------

@@ -413,6 +413,16 @@ def _spawn_service(port, store, snaps):
     )
 
 
+def _has_snapshot(path, cid):
+    """True once the snapshot log holds a whole record for ``cid``."""
+    try:
+        with open(path) as f:
+            lines = [ln for ln in f if ln.endswith("\n")]
+    except FileNotFoundError:
+        return False
+    return any(json.loads(ln).get("id") == cid for ln in lines)
+
+
 def _wait_healthy(cli, proc, timeout=60.0):
     t0 = time.time()
     while time.time() - t0 < timeout:
@@ -450,8 +460,10 @@ def test_kill9_service_restart_resume_front_is_byte_identical(tmp_path):
             st = cli.status(cid)
             if st["state"] == "done":
                 break  # raced to completion before we could kill
-            if (st.get("progress") or {}).get("stage") in ("explore",
-                                                           "final"):
+            # the stage turns to "explore" a moment before its tick's
+            # snapshot is written: kill only once the record is on disk
+            if (st.get("progress") or {}).get("stage") in (
+                    "explore", "final") and _has_snapshot(snaps, cid):
                 break
             time.sleep(0.01)
         if st["state"] == "done":        # raced: nothing left to kill mid-run

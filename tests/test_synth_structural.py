@@ -189,6 +189,87 @@ def test_persistent_cache_cold_then_warm_zero_compiles(tmp_path):
         assert np.array_equal(ref[k], new[k]), k
 
 
+def test_jax_compile_cache_never_serves_a_synthesis_compile(
+        tmp_path, monkeypatch):
+    """With JAX's persistent compile cache on and keeping every compile,
+    a synthesis compile is neither written there nor read back, so
+    wall_time always times a compile; the compile-derived labels of a
+    second pass (cold in-memory caches) equal the first's."""
+    import os
+
+    import jax
+    from jax import monitoring
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    accel = GaussianFilter()
+    genomes = _random_variants(accel, 3, 29)
+    inputs = accel.sample_inputs(2, seed=2)
+    cache_dir = str(tmp_path / "jax_cache")
+    seen = {"written": [], "hits": 0}
+    inner = synth._compile_cost_uncached
+    in_synth = []
+
+    def watched(fn, args, **kw):
+        before = set(os.listdir(cache_dir)) if os.path.isdir(cache_dir) \
+            else set()
+        in_synth.append(True)
+        try:
+            return inner(fn, args, **kw)
+        finally:
+            in_synth.pop()
+            after = set(os.listdir(cache_dir)) if os.path.isdir(cache_dir) \
+                else set()
+            seen["written"] += sorted(after - before)
+
+    control_hits = []
+
+    def on_event(event, **kw):
+        if event == "/jax/compilation_cache/cache_hits":
+            if in_synth:
+                seen["hits"] += 1
+            else:
+                control_hits.append(event)
+
+    monkeypatch.setattr(synth, "_compile_cost_uncached", watched)
+    prev = (jax.config.jax_compilation_cache_dir,
+            jax.config.jax_persistent_cache_min_compile_time_secs)
+    jax.config.update("jax_compilation_cache_dir", cache_dir)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    cc.reset_cache()
+    monitoring.register_event_listener(on_event)
+    try:
+        passes = []
+        for _ in range(2):
+            synth.reset_fast_codegen()
+            jax.clear_caches()
+            passes.append(synth.label_variants(
+                accel, genomes, LIB, qor_inputs=inputs,
+                synth_cache=synth.SynthCache()))
+        # control: an ordinary compile does land in the cache, and the
+        # executable read back from it carries the same cost analysis
+        x = jax.ShapeDtypeStruct((64, 64), np.float32)
+        f = jax.jit(lambda a: a @ a + 1.0)
+        fresh = f.lower(x).compile().cost_analysis()
+        jax.clear_caches()
+        control_hits.clear()
+        hit = f.lower(x).compile().cost_analysis()
+        assert control_hits, "the control's second compile was a cache hit"
+    finally:
+        monitoring.unregister_event_listener(on_event)
+        jax.config.update("jax_compilation_cache_dir", prev[0])
+        jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                          prev[1])
+        cc.reset_cache()
+    assert seen == {"written": [], "hits": 0}, seen
+    assert os.listdir(cache_dir), "the control compile was cached"
+    assert hit["flops"] > 0 and hit["bytes accessed"] > 0, hit
+    assert {k: hit[k] for k in synth._COST_KEYS} == \
+        {k: fresh[k] for k in synth._COST_KEYS}
+    for k in HW_KEYS:
+        assert np.array_equal(passes[0][k], passes[1][k]), k
+        assert (np.asarray(passes[0][k]) > 0).all(), k
+
+
 def test_persistent_cache_verification_state_survives_restart(tmp_path):
     """A family verified cold stays verified warm: a NEVER-seen identity
     of a known structure is served with zero compiles after a restart."""
