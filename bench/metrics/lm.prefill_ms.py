@@ -1,0 +1,9 @@
+"""Device milliseconds per prefill: time of the XLA module
+``jit_prefill`` in the traced window over its executions."""
+
+from benchkit.devtrace import module_time
+
+
+def read(ctx):
+    m = module_time(ctx["trace"], "jit_prefill")
+    return None if m is None else m[1] * 1e3 / m[0]
