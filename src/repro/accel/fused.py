@@ -73,6 +73,7 @@ _M16 = (1 << 16) - 1
 
 _STATS_LOCK = threading.Lock()
 _STATS: Dict[str, int] = {}
+_COUNTERS: Dict[str, object] = {}       # key -> repro_sim_fused_<key>_total
 _JIT_CACHE: Dict[tuple, Callable] = {}
 _PLAN_CACHE: Dict[tuple, Optional["FusedPlan"]] = {}
 _ENGINES: Dict[str, "_Engine"] = {}
@@ -97,14 +98,15 @@ def _verify_budget() -> int:
 def _bump(key: str, n: int = 1) -> None:
     with _STATS_LOCK:
         _STATS[key] = _STATS.get(key, 0) + n
-    try:
-        from .. import obs
+        counter = _COUNTERS.get(key)
+        if counter is None:
+            from .. import obs
 
-        obs.REGISTRY.counter(
-            f"repro_sim_fused_{key}_total", f"fused sim engine: {key}"
-        ).inc(n)
-    except Exception:
-        pass
+            # registered once: the registry replaces a name on every
+            # registration, which would drop the count so far
+            counter = _COUNTERS[key] = obs.REGISTRY.counter(
+                f"repro_sim_fused_{key}_total", f"fused sim engine: {key}")
+    counter.inc(n)
 
 
 def stats() -> Dict[str, int]:

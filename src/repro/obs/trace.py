@@ -22,9 +22,24 @@ Records land in a bounded in-memory ring plus an optional JSONL sink
 (``--trace`` on the service CLI); ``python -m repro.obs.export
 --chrome-trace`` turns the sink file into a Perfetto-loadable trace.
 
+Once ``jax`` is imported, the recorder bridges to it twice.  Each
+``span`` also enters ``jax.profiler.TraceAnnotation(name,
+span=<id>)`` for its duration, so a profile taken with
+``jax.profiler.start_trace`` holds it on the host plane, joinable to its
+record by the span id (``start_span`` and ingested spans end elsewhere
+and are not bridged).  And ``jax.monitoring`` listeners turn JAX's
+compile events into finished ``jit.compile`` records parented to the
+span open on the compiling thread: ``attrs.phase`` is ``trace`` (jaxpr
+tracing, with the sub-traces nested in it folded in and counted in
+``attrs.nested``), ``lower`` (to StableHLO) or ``backend`` (XLA's
+compile), ``attrs.fun`` the function.  Every backend compile also counts
+in ``repro_jit_compiles_total``, whether or not tracing is on.
+
 Tracing is on by default and costs two clock reads plus a deque append
-per span; ``REPRO_OBS=0`` (or ``set_enabled(False)``) turns every
-``span``/``context`` into a no-op for overhead benchmarking.
+per span (about half a microsecond more for the annotation while no
+profiler runs); ``REPRO_OBS=0`` (or ``set_enabled(False)``) turns every
+``span``/``context``, the annotations and the ``jit.compile`` records
+into no-ops for overhead benchmarking.
 """
 
 from __future__ import annotations
@@ -32,6 +47,7 @@ from __future__ import annotations
 import contextvars
 import json
 import os
+import sys
 import threading
 import time
 import uuid
@@ -94,7 +110,8 @@ class Recorder:
         self._sink_file = None
         self.n_spans = 0
         self.n_ingested = 0
-        self.n_dropped = 0  # sink write failures, not ring evictions
+        self.n_dropped = 0  # sink write failures
+        self.n_evicted = 0  # records the bounded ring pushed out
         if sink:
             self.set_sink(sink)
 
@@ -112,12 +129,10 @@ class Recorder:
                 os.makedirs(d, exist_ok=True)
                 self._sink_file = open(path, "a", encoding="utf-8")
 
-    @property
-    def sink_path(self) -> Optional[str]:
-        return self._sink_path
-
     def emit(self, rec: Dict) -> None:
         with self._lock:
+            if len(self._ring) == self._ring.maxlen:
+                self.n_evicted += 1
             self._ring.append(rec)
             self.n_spans += 1
             if self._sink_file is not None:
@@ -153,6 +168,7 @@ class Recorder:
                 "spans": self.n_spans,
                 "ingested": self.n_ingested,
                 "ring": len(self._ring),
+                "evicted": self.n_evicted,
                 "sink": self._sink_path,
                 "sink_drops": self.n_dropped,
             }
@@ -268,17 +284,104 @@ def start_span(name: str, **attrs) -> Span:
 
 @contextmanager
 def span(name: str, **attrs):
-    """Time a region; nested spans parent to it via the contextvar."""
+    """Time a region; nested spans parent to it via the contextvar.
+    With ``jax`` imported the region is also a profiler annotation."""
+    annotation = _annotation or _jax_bridge()
     if not _enabled:
         yield _NULL
         return
     s = Span(name, _current.get(), attrs, _recorder)
     token = _current.set(_Ctx(s.trace_id, s.span_id, dict(s.attrs)))
+    ann = annotation(name, span=s.span_id) if annotation else None
+    if ann is not None:
+        ann.__enter__()
     try:
         yield s
     finally:
+        if ann is not None:
+            ann.__exit__(None, None, None)
         _current.reset(token)
         s.end()
+
+
+# ----------------------------------------------------------------------
+# jax bridge: profiler annotations and compile events
+
+_PHASES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend",
+}
+_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+_annotation = None          # jax.profiler.TraceAnnotation once resolved
+_bridge_lock = threading.Lock()
+_tracing = threading.local()  # per thread: sub-trace counts of open traces
+_jit_compiles = None        # repro_jit_compiles_total
+
+
+def _jax_bridge():
+    """Resolve ``TraceAnnotation`` and install the compile listener, once
+    and only after ``jax`` has been imported (obs never imports it)."""
+    global _annotation, _jit_compiles
+    jax = sys.modules.get("jax")
+    profiler = getattr(jax, "profiler", None)
+    monitoring = getattr(jax, "monitoring", None)
+    if profiler is None or monitoring is None:
+        return None             # no jax, or jax still importing
+    with _bridge_lock:
+        if _annotation is None:
+            from .metrics import REGISTRY
+
+            _jit_compiles = REGISTRY.counter(
+                "repro_jit_compiles_total",
+                "XLA backend compiles in this process (a recompile alarm)")
+            monitoring.register_scalar_listener(_on_jax_start)
+            monitoring.register_event_duration_secs_listener(_on_jax_duration)
+            _annotation = profiler.TraceAnnotation
+    return _annotation
+
+
+def _on_jax_start(event: str, value, **kw) -> None:
+    # JAX reports each compile phase's start as a scalar; only the trace
+    # phase nests (a traced function traces what it calls)
+    if event == _TRACE_EVENT:
+        stack = getattr(_tracing, "stack", None)
+        if stack is None:
+            stack = _tracing.stack = []
+        stack.append(0)
+
+
+def _on_jax_duration(event: str, duration: float, **kw) -> None:
+    phase = _PHASES.get(event)
+    if phase is None:
+        return
+    end = time.time()
+    attrs = {"phase": phase, "fun": kw.get("fun_name")}
+    if phase == "backend":
+        _jit_compiles.inc()
+    elif phase == "trace":
+        stack = getattr(_tracing, "stack", None)
+        nested = stack.pop() if stack else 0
+        if stack:
+            stack[-1] += 1 + nested     # folded into the enclosing trace
+            return
+        attrs["nested"] = nested
+    if not _enabled:
+        return
+    ctx = _current.get()
+    if ctx is not None:
+        attrs = {**ctx.baggage, **attrs}
+    _recorder.emit({
+        "name": "jit.compile",
+        "trace": ctx.trace_id if ctx is not None else _new_id(),
+        "span": _new_id(),
+        "parent": ctx.span_id if ctx is not None else None,
+        "t0": round(end - duration, 6),
+        "dur": round(duration, 6),
+        "pid": os.getpid(),
+        "tid": threading.get_ident() & 0xFFFFFFFF,
+        "attrs": {k: v for k, v in attrs.items() if v is not None},
+    })
 
 
 # ----------------------------------------------------------------------

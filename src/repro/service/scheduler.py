@@ -89,6 +89,7 @@ class _Entry:
     # trace context captured at submit() so the batch span (run on a
     # pool thread) links back to the submitting campaign's trace
     wire: Optional[dict] = None
+    t_submit: float = field(default_factory=time.perf_counter)
 
 
 class EvalScheduler:
@@ -362,9 +363,11 @@ class EvalScheduler:
         ctx = batch[0].ctx
         head = batch[0]
         t0 = time.perf_counter()
+        # mean time the batch's labels waited between submit and now
+        wait_s = sum(t0 - e.t_submit for e in batch) / len(batch)
         with obs.attach(head.wire), \
-                obs.span("sched.batch", n=len(batch),
-                         origin=head.origin) as sp:
+                obs.span("sched.batch", n=len(batch), origin=head.origin,
+                         wait_s=round(wait_s, 6)) as sp:
             try:
                 faults.hit("sched.dispatch", n=len(batch),
                            origin=head.origin)

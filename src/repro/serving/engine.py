@@ -18,8 +18,10 @@ it in mid-run without dropping a request — search while serving.
 
 The ``serving.request`` span starts in the submitter's trace context
 (trace id flows through batch formation into the group execution
-attrs); counters ride the PR-7 sharded registry and surface as
-``repro_serving_*`` on ``GET /metrics`` and in ``GET /serving/stats``.
+attrs) and carries ``queue_s``, submit until the batch loop popped the
+request from the admission queue; counters ride the sharded registry
+and surface as ``repro_serving_*`` on ``GET /metrics`` and in
+``GET /serving/stats``.
 """
 
 from __future__ import annotations
@@ -346,9 +348,12 @@ class ServingEngine:
                     self._queue.popleft()
                     for _ in range(min(self.max_batch, len(self._queue)))
                 ]
+                popped = time.perf_counter()
                 self._m["depth"].set(len(self._queue))
                 catalog = self._catalog
                 catalogs = dict(self._catalogs)
+            for req in batch:
+                req.span.set(queue_s=round(popped - req.t_submit, 6))
             if batch:
                 try:
                     self._run_batch(batch, catalog, catalogs)

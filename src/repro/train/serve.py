@@ -13,6 +13,7 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from .. import obs
 from ..models import ApproxPolicy
 from ..models.config import ModelConfig
 from ..models.transformer import (
@@ -109,7 +110,9 @@ class Generator:
         """Greedy-decode ``gen`` tokens after ``prompts`` (b, L) int32.
         Synthesizes the frontend extras reduced archs need (encoder
         embeds for enc-dec, vision embeds for vision frontends).
-        Returns (tokens (b, L + gen), decode tokens/s)."""
+        Returns (tokens (b, L + gen), decode tokens/s).  The prefill
+        (through its first token) and the decode loop run under the
+        ``lm.prefill`` and ``lm.decode`` spans."""
         import time
 
         from ..models.common import init_tree
@@ -135,26 +138,28 @@ class Generator:
                 key, (batch, cfg.frontend_len, cfg.d_model),
                 jnp.float32) * 0.1
 
-        out = self.prefill(params, batch_in, caches)
-        enc_out = None
-        if cfg.is_encoder_decoder:
-            logits, caches, enc_out = out
-        else:
-            logits, caches = out
-        nxt = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)[:, None]
+        with obs.span("lm.prefill", batch=batch, prompt_len=prompt_len):
+            out = self.prefill(params, batch_in, caches)
+            enc_out = None
+            if cfg.is_encoder_decoder:
+                logits, caches, enc_out = out
+            else:
+                logits, caches = out
+            nxt = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)[:, None]
+            nxt.block_until_ready()
 
         toks = [prompts, nxt]
         pos0 = prompt_len + vis
         # the decode clock covers the device's work and nothing else:
         # prefill has finished when it starts, the last token when it stops
-        nxt.block_until_ready()
         t0 = time.perf_counter()
-        for i in range(int(gen) - 1):
-            nxt, logits, caches = self.decode(
-                params, caches, nxt, jnp.int32(pos0 + i), enc_out=enc_out
-            )
-            toks.append(nxt)
-        nxt.block_until_ready()
+        with obs.span("lm.decode", batch=batch, steps=int(gen) - 1):
+            for i in range(int(gen) - 1):
+                nxt, logits, caches = self.decode(
+                    params, caches, nxt, jnp.int32(pos0 + i), enc_out=enc_out
+                )
+                toks.append(nxt)
+            nxt.block_until_ready()
         dt = time.perf_counter() - t0
         tokens = jnp.concatenate(toks, axis=1)
         tps = batch * (int(gen) - 1) / max(dt, 1e-9)

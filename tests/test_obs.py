@@ -119,9 +119,123 @@ def test_recorder_ring_bound_and_ingest():
         rec.emit({"name": f"s{i}", "t0": 0.0, "dur": 0.0})
     assert len(rec.snapshot()) == 4
     assert rec.stats()["spans"] == 10
+    assert rec.stats()["evicted"] == 6
     rec.ingest([{"name": "far", "t0": 0.0}, {"bogus": 1}, "junk"])
     assert rec.stats()["ingested"] == 1
+    assert rec.stats()["evicted"] == 7
     assert rec.snapshot()[-1]["name"] == "far"
+    rec.clear()                 # a clear is not an eviction
+    rec.emit({"name": "after", "t0": 0.0, "dur": 0.0})
+    assert rec.stats()["evicted"] == 7
+
+
+def test_jit_compile_records_parent_to_the_open_span():
+    import jax
+    import jax.numpy as jnp
+
+    x = jnp.ones((4, 4))
+    with obs.span("compile.warm"):
+        pass                    # jax is imported: the bridge is installed
+    compiles = obs.REGISTRY.get("repro_jit_compiles_total")
+    before = compiles.value
+    rec = obs.recorder()
+    rec.clear()
+
+    def f(x):
+        return jnp.sin(x) @ x.T
+
+    with obs.context(campaign="c-jit"):
+        with obs.span("compile.op") as sp:
+            jax.jit(f).lower(x).compile()
+    recs = [r for r in rec.snapshot() if r["name"] == "jit.compile"]
+    by_phase = {r["attrs"]["phase"]: r for r in recs}
+    assert sorted(r["attrs"]["phase"] for r in recs) == ["backend", "lower", "trace"]
+    assert all(r["parent"] == sp.span_id for r in recs)
+    assert all(r["attrs"]["campaign"] == "c-jit" for r in recs)
+    # the sub-traces of sin and matmul fold into f's own trace
+    assert by_phase["trace"]["attrs"]["fun"] == "f"
+    assert by_phase["trace"]["attrs"]["nested"] >= 2
+    assert by_phase["lower"]["attrs"]["fun"] == "jit(f)"
+    assert by_phase["backend"]["attrs"]["fun"] == "jit(f)"
+    op = [r for r in rec.snapshot() if r["name"] == "compile.op"][0]
+    t = by_phase["trace"]
+    assert op["t0"] - 1e-3 <= t["t0"] <= by_phase["lower"]["t0"]
+    assert by_phase["backend"]["t0"] + by_phase["backend"]["dur"] <= (
+        op["t0"] + op["dur"] + 1e-3)
+    assert compiles.value >= before + 1
+    text = obs.render_prometheus()
+    assert "# TYPE repro_jit_compiles_total counter" in text
+
+
+def test_fused_counters_count_across_calls():
+    from repro.accel import fused
+
+    name = "repro_sim_fused_obs_probe_total"
+    try:
+        fused._bump("obs_probe")
+        fused._bump("obs_probe")
+        assert _parse_prometheus(obs.render_prometheus())[name] == 2
+        fused._bump("obs_probe", 3)
+        assert obs.REGISTRY.get(name).value == 5
+    finally:
+        with fused._STATS_LOCK:
+            fused._STATS.pop("obs_probe", None)
+
+
+def test_scheduler_batch_span_carries_wait_s():
+    """Labels that wait behind a busy worker show it in ``wait_s``."""
+    rec = obs.recorder()
+    rec.clear()
+    sched = EvalScheduler(InMemoryLabelStore(), n_workers=1,
+                          max_batch=4, max_wait_s=0.0)
+    try:
+        sched.label(_SlowCtx(delay=0.1), np.arange(16).reshape(8, 2))
+    finally:
+        sched.shutdown()
+    batches = [s for s in rec.snapshot() if s["name"] == "sched.batch"]
+    assert sum(s["attrs"]["n"] for s in batches) == 8
+    waits = sorted(s["attrs"]["wait_s"] for s in batches)
+    assert waits[0] >= 0.0
+    # the later batch waited for the earlier one's 0.1 s ground truth
+    assert waits[-1] >= 0.09
+
+
+def test_lm_engine_spans_queue_and_model_step():
+    """The LM serving path on a tiny model: each request's admission
+    wait, and the model step's prefill and decode as children of its
+    group."""
+    from repro.accel.lm import LMAccelerator
+    from repro.configs import get_config
+    from repro.models import reduced
+    from repro.serving import FrontCatalog, ServingEngine
+
+    cfg = reduced(get_config("granite-8b"), n_layers=1, d_model=32)
+    accel = LMAccelerator(cfg, use_reduced=False)
+    lib = default_library()
+    catalog = FrontCatalog.from_front(
+        accel.name, [accel.exact_genome(lib).tolist()], [[-100.0, 1.0]])
+    rec = obs.recorder()
+    rec.clear()
+    eng = ServingEngine(accel, lib, catalog=catalog, max_batch=4,
+                        max_wait_s=0.2)
+    try:
+        prompts = np.arange(16, dtype=np.int32).reshape(2, 8) % cfg.vocab_size
+        futs = [eng.submit(p, tier="exact", gen=3) for p in prompts]
+        for f in futs:
+            assert len(f.result(timeout=300)["tokens"]) == 3
+    finally:
+        eng.close()
+    spans = rec.snapshot()
+    reqs = [s for s in spans if s["name"] == "serving.request"]
+    assert len(reqs) == 2
+    # two requests of a possible four: the batch loop lingered for more
+    assert all(0.1 <= s["attrs"]["queue_s"] < 60 for s in reqs)
+    group, = [s for s in spans if s["name"] == "serving.group"]
+    steps = {s["name"]: s for s in spans if s["parent"] == group["span"]
+             and s["name"].startswith("lm.")}
+    assert set(steps) == {"lm.prefill", "lm.decode"}
+    assert steps["lm.decode"]["attrs"]["steps"] == 2
+    assert steps["lm.prefill"]["dur"] + steps["lm.decode"]["dur"] <= group["dur"]
 
 
 # ---------------------------------------------------------------------------
@@ -429,6 +543,7 @@ def test_campaign_timeline_and_metrics_endpoints(tmp_path):
         assert samples_m["repro_sched_requests_total"] > 0
         assert samples_m["repro_sched_batches_total"] > 0
         assert any(k.startswith("repro_synth_") for k in samples_m)
+        assert samples_m["repro_jit_compiles_total"] > 0
 
         stats = json.load(urllib.request.urlopen(f"{base}/stats"))
         assert stats["obs"]["recorder"]["spans"] > 0
