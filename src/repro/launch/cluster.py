@@ -99,7 +99,7 @@ def main(argv: Optional[list] = None):
     from ..data.pipeline import TokenPipeline
     from ..dist.sharding import rule_overrides
     from ..models import reduced as reduce_cfg
-    from ..models.common import abstract_tree, init_tree
+    from ..models.common import init_tree, with_dtype
     from ..models.transformer import param_specs
     from ..optim.adamw import AdamW
     from ..train.step import init_state, make_train_step
@@ -131,7 +131,7 @@ def main(argv: Optional[list] = None):
     from ..dist.compat import mesh_context
 
     with mesh_context(mesh), rule_overrides(rules):
-        specs = param_specs(cfg)
+        specs = with_dtype(param_specs(cfg), cfg.param_dtype)
         latest = ckpt.latest_step(args.ckpt_dir) if pi == 0 else None
         params = init_tree(specs, jax.random.PRNGKey(0))
         state = init_state(params, opt)

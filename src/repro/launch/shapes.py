@@ -25,7 +25,7 @@ import jax
 import jax.numpy as jnp
 
 from ..dist.sharding import AxisRules, sharding_for
-from ..models.common import ParamSpec, abstract_tree
+from ..models.common import abstract_tree, with_dtype
 from ..models.config import ModelConfig
 from ..models.transformer import cache_specs, param_specs
 
@@ -142,10 +142,7 @@ def input_specs(
     # train: master-weight dtype from the config (jamba: bf16 to fit HBM);
     # serving: bf16 weights
     dtype = cfg.param_dtype if cell.kind == "train" else serve_dtype
-    pspecs = jax.tree.map(
-        lambda s: ParamSpec(s.shape, s.logical, dtype, s.init, s.scale),
-        pspecs, is_leaf=lambda s: isinstance(s, ParamSpec),
-    )
+    pspecs = with_dtype(pspecs, dtype)
     out["param_specs"] = pspecs
     out["params"] = abstract_tree(pspecs, mesh, rules)
 

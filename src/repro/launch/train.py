@@ -23,7 +23,7 @@ from ..checkpoint import ckpt
 from ..configs import get_config
 from ..data.pipeline import TokenPipeline
 from ..models import ApproxPolicy, reduced
-from ..models.common import init_tree
+from ..models.common import init_tree, with_dtype
 from ..models.transformer import param_specs
 from ..optim.adamw import AdamW
 from ..train.step import init_state, make_train_step
@@ -56,20 +56,23 @@ def train_loop(
         attn_chunk=attn_chunk, scan_chunk=scan_chunk,
     ), donate_argnums=(0,))
 
+    # master weights in the config's training type (the declaration holds
+    # the serving types)
+    specs = with_dtype(param_specs(cfg), cfg.param_dtype)
     start = 0
     state = None
     if ckpt_dir is not None:
         latest = ckpt.latest_step(ckpt_dir)
         if latest is not None:
             like = init_state(
-                init_tree(param_specs(cfg), jax.random.PRNGKey(seed)), opt,
+                init_tree(specs, jax.random.PRNGKey(seed)), opt,
                 compress=compress,
             )
             state = ckpt.restore(ckpt_dir, latest, like)
             start = latest
             print(f"[train] restored checkpoint @ step {latest}")
     if state is None:
-        params = init_tree(param_specs(cfg), jax.random.PRNGKey(seed))
+        params = init_tree(specs, jax.random.PRNGKey(seed))
         state = init_state(params, opt, compress=compress)
 
     losses = []

@@ -77,7 +77,9 @@ def _approx_matmul_nd(x: jnp.ndarray, w: jnp.ndarray, spec) -> jnp.ndarray:
     k = x.shape[-1]
     x2 = x.reshape(-1, k)
     qx, sx = quantize_sym(x2)
-    qw, sw = quantize_sym(w)
+    # the weight's scale and rounding are taken in float32 whatever its
+    # storage type (exact: every bfloat16 value is a float32 value)
+    qw, sw = quantize_sym(w.astype(jnp.float32))
     if spec.trunc_bits:
         # natively-truncating circuit: reduced-width integer operands
         t = spec.trunc_bits

@@ -26,12 +26,13 @@ __all__ = [
 def attn_param_specs(cfg: ModelConfig, cross: bool = False) -> Dict[str, ParamSpec]:
     d = cfg.d_model
     hd = cfg.resolved_head_dim
+    dt = cfg.compute_dtype  # read only through ``linear``
     p = {
         "norm": ParamSpec((d,), ("norm",), init="zeros"),
-        "wq": ParamSpec((d, cfg.n_heads * hd), ("embed", "heads")),
-        "wk": ParamSpec((d, cfg.n_kv_heads * hd), ("embed", "kv_heads")),
-        "wv": ParamSpec((d, cfg.n_kv_heads * hd), ("embed", "kv_heads")),
-        "wo": ParamSpec((cfg.n_heads * hd, d), ("heads", "embed")),
+        "wq": ParamSpec((d, cfg.n_heads * hd), ("embed", "heads"), dt),
+        "wk": ParamSpec((d, cfg.n_kv_heads * hd), ("embed", "kv_heads"), dt),
+        "wv": ParamSpec((d, cfg.n_kv_heads * hd), ("embed", "kv_heads"), dt),
+        "wo": ParamSpec((cfg.n_heads * hd, d), ("heads", "embed"), dt),
     }
     return p
 

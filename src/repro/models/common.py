@@ -5,6 +5,13 @@ Parameters are plain nested dicts of jnp arrays.  Their shapes/logical
 axes are declared once via ``ParamSpec``; ``init_tree`` materializes real
 arrays (smoke tests / examples) and ``abstract_tree`` materializes
 ShapeDtypeStructs with NamedShardings (dry-run) from the same declaration.
+
+A leaf that every reader casts to the compute type before use (the
+projections, embedding and LM head) is declared in ``cfg.compute_dtype``,
+so serving stores it once in that type instead of casting it on every
+step; leaves read in float32 (norm scales, router, SSM dynamics) keep
+float32.  Training maps the whole tree to ``cfg.param_dtype`` with
+``with_dtype`` for its master weights.
 """
 
 from __future__ import annotations
@@ -20,6 +27,7 @@ __all__ = [
     "ParamSpec",
     "init_tree",
     "abstract_tree",
+    "with_dtype",
     "rms_norm",
     "make_rope",
     "apply_rope",
@@ -59,6 +67,15 @@ def init_tree(specs, key: jax.Array):
             ).astype(spec.dtype)
         out.append(arr)
     return jax.tree.unflatten(treedef, out)
+
+
+def with_dtype(specs, dtype: str):
+    """The same ParamSpec tree with every leaf declared in ``dtype``."""
+    return jax.tree.map(
+        lambda s: ParamSpec(s.shape, s.logical, dtype, s.init, s.scale),
+        specs,
+        is_leaf=_is_spec,
+    )
 
 
 def abstract_tree(specs, mesh, rules=None):

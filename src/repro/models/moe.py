@@ -22,12 +22,12 @@ __all__ = ["moe_param_specs", "moe_layer", "dense_mlp_param_specs", "dense_mlp"]
 
 
 def dense_mlp_param_specs(cfg: ModelConfig) -> Dict[str, ParamSpec]:
-    d, f = cfg.d_model, cfg.d_ff
+    d, f, dt = cfg.d_model, cfg.d_ff, cfg.compute_dtype
     return {
         "norm": ParamSpec((d,), ("norm",), init="zeros"),
-        "wi": ParamSpec((d, f), ("embed", "mlp")),
-        "wg": ParamSpec((d, f), ("embed", "mlp")),
-        "wo": ParamSpec((f, d), ("mlp", "embed")),
+        "wi": ParamSpec((d, f), ("embed", "mlp"), dt),
+        "wg": ParamSpec((d, f), ("embed", "mlp"), dt),
+        "wo": ParamSpec((f, d), ("mlp", "embed"), dt),
     }
 
 
@@ -47,12 +47,13 @@ def dense_mlp(
 
 def moe_param_specs(cfg: ModelConfig) -> Dict[str, ParamSpec]:
     d, f, e = cfg.d_model, cfg.d_ff, cfg.padded_experts
+    dt = cfg.compute_dtype  # the experts are read cast to bfloat16
     return {
         "norm": ParamSpec((d,), ("norm",), init="zeros"),
         "router": ParamSpec((d, e), ("embed", None)),
-        "wi": ParamSpec((e, d, f), ("experts", "embed", "expert_mlp")),
-        "wg": ParamSpec((e, d, f), ("experts", "embed", "expert_mlp")),
-        "wo": ParamSpec((e, f, d), ("experts", "expert_mlp", "embed")),
+        "wi": ParamSpec((e, d, f), ("experts", "embed", "expert_mlp"), dt),
+        "wg": ParamSpec((e, d, f), ("experts", "embed", "expert_mlp"), dt),
+        "wo": ParamSpec((e, f, d), ("experts", "expert_mlp", "embed"), dt),
     }
 
 

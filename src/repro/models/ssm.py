@@ -41,17 +41,18 @@ def set_scan_dtype(dt: str) -> None:
 def mamba_param_specs(cfg: ModelConfig) -> Dict[str, ParamSpec]:
     d, di = cfg.d_model, cfg.d_inner
     n, dtr, ck = cfg.ssm_state, cfg.resolved_dt_rank, cfg.ssm_conv
+    dt = cfg.compute_dtype  # the projections are read only through ``linear``
     return {
         "norm": ParamSpec((d,), ("norm",), init="zeros"),
-        "in_proj": ParamSpec((d, 2 * di), ("embed", "mlp")),
+        "in_proj": ParamSpec((d, 2 * di), ("embed", "mlp"), dt),
         "conv_w": ParamSpec((ck, di), ("conv", "mlp"), scale=0.1),
         "conv_b": ParamSpec((di,), ("mlp",), init="zeros"),
-        "x_proj": ParamSpec((di, dtr + 2 * n), ("mlp", None)),
-        "dt_proj": ParamSpec((dtr, di), ("dt", "mlp")),
+        "x_proj": ParamSpec((di, dtr + 2 * n), ("mlp", None), dt),
+        "dt_proj": ParamSpec((dtr, di), ("dt", "mlp"), dt),
         "dt_bias": ParamSpec((di,), ("mlp",), init="ones", scale=1.0),
         "A_log": ParamSpec((di, n), ("mlp", "state"), init="ones"),
         "D": ParamSpec((di,), ("mlp",), init="ones"),
-        "out_proj": ParamSpec((di, d), ("mlp", "embed")),
+        "out_proj": ParamSpec((di, d), ("mlp", "embed"), dt),
     }
 
 

@@ -67,18 +67,18 @@ def _stack_specs(specs, n: int):
 
 
 def param_specs(cfg: ModelConfig) -> Dict[str, Any]:
-    d, v = cfg.d_model, cfg.padded_vocab
+    d, v, dt = cfg.d_model, cfg.padded_vocab, cfg.compute_dtype
     blk = {
         f"layer{i}": _layer_specs(cfg, kind)
         for i, kind in enumerate(cfg.block_pattern)
     }
     out: Dict[str, Any] = {
-        "embed": ParamSpec((v, d), ("vocab", "embed")),
+        "embed": ParamSpec((v, d), ("vocab", "embed"), dt),
         "blocks": _stack_specs(blk, cfg.n_superblocks),
         "final_norm": ParamSpec((d,), ("norm",), init="zeros"),
     }
     if not cfg.tie_embeddings:
-        out["lm_head"] = ParamSpec((d, v), ("embed", "vocab"))
+        out["lm_head"] = ParamSpec((d, v), ("embed", "vocab"), dt)
     if cfg.is_encoder_decoder:
         enc_layer = {
             "attn": attn_param_specs(cfg),

@@ -8,6 +8,7 @@ device for the large-vocab archs.
 
 from __future__ import annotations
 
+import weakref
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -98,6 +99,20 @@ class Generator:
             cfg, policy=policy, attn_chunk=attn_chunk,
             scan_chunk=scan_chunk))
         self.decode = jax.jit(make_decode_step(cfg, policy=policy))
+        self._tree_bytes: Dict[int, int] = {}
+
+    def weight_bytes(self, params) -> int:
+        """Bytes of the parameter tree, summed once per tree: the entry is
+        keyed by the tree's identity and dropped with its first leaf."""
+        key = id(params)
+        n = self._tree_bytes.get(key)
+        if n is None:
+            leaves = jax.tree.leaves(params)
+            n = sum(int(leaf.nbytes) for leaf in leaves)
+            self._tree_bytes[key] = n
+            if leaves:
+                weakref.finalize(leaves[0], self._tree_bytes.pop, key, None)
+        return n
 
     def generate(
         self,
@@ -112,7 +127,8 @@ class Generator:
         embeds for enc-dec, vision embeds for vision frontends).
         Returns (tokens (b, L + gen), decode tokens/s).  The prefill
         (through its first token) and the decode loop run under the
-        ``lm.prefill`` and ``lm.decode`` spans."""
+        ``lm.prefill`` and ``lm.decode`` spans; ``lm.prefill`` records the
+        bytes of the weights that served (``weight_bytes``)."""
         import time
 
         from ..models.common import init_tree
@@ -138,7 +154,8 @@ class Generator:
                 key, (batch, cfg.frontend_len, cfg.d_model),
                 jnp.float32) * 0.1
 
-        with obs.span("lm.prefill", batch=batch, prompt_len=prompt_len):
+        with obs.span("lm.prefill", batch=batch, prompt_len=prompt_len,
+                      weight_bytes=self.weight_bytes(params)):
             out = self.prefill(params, batch_in, caches)
             enc_out = None
             if cfg.is_encoder_decoder:
