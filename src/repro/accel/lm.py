@@ -41,14 +41,22 @@ def proj_classes_for(cfg: ModelConfig) -> List[Tuple[str, float]]:
     has_attn = any(k.mixer == "attn" for k in cfg.block_pattern)
     if has_attn:
         out += [("qkv", qkv), ("attn_out", attn_out)]
-    if any(k.mlp == "dense" for k in cfg.block_pattern):
-        out += [("ffn_in", 2.0 * d * ff), ("ffn_out", d * ff)]
+    # the dense MLP and the MoE's shared expert both run through "ffn_*"
+    ffn = ff if any(k.mlp == "dense" for k in cfg.block_pattern) else 0
+    ffn += cfg.shared_ff
+    if ffn:
+        out += [("ffn_in", 2.0 * d * ffn), ("ffn_out", d * ffn)]
     if cfg.n_experts:
         act = cfg.n_experts_active
         out += [("expert_in", 2.0 * d * ff * act), ("expert_out", d * ff * act)]
     if any(k.mixer == "mamba" for k in cfg.block_pattern):
         di = cfg.d_inner
-        out += [("ssm_in", 2.0 * d * di), ("ssm_out", di * d)]
+        if cfg.ssm_kind == "mamba2":  # in_proj -> z, xBC, dt
+            ssm_in = d * (2 * di + 2 * cfg.ssm_groups * cfg.ssm_state
+                          + cfg.ssm_heads)
+        else:
+            ssm_in = 2.0 * d * di
+        out += [("ssm_in", ssm_in), ("ssm_out", di * d)]
     out += [("lm_head", head)]
     total = sum(w for _, w in out)
     return [(c, w / total) for c, w in out]

@@ -8,6 +8,7 @@ _MODULES = {
     "gemma-2b": "gemma_2b",
     "chatglm3-6b": "chatglm3_6b",
     "granite-8b": "granite_8b",
+    "granite-4.0-h-small": "granite_4_h_small",
     "seamless-m4t-medium": "seamless_m4t_medium",
     "phi3.5-moe-42b-a6.6b": "phi35_moe",
     "granite-moe-3b-a800m": "granite_moe_3b",

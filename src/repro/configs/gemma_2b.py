@@ -8,4 +8,5 @@ CONFIG = ModelConfig(
     d_ff=16384, vocab_size=256000,
     mlp_act="gelu", tie_embeddings=True,
     rope_theta=10000.0,
+    embedding_multiplier=2048 ** 0.5,  # sqrt(d_model), in bfloat16
 )

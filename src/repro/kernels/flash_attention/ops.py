@@ -25,14 +25,18 @@ def attention(
     chunk: int = 1024,
     q_offset: int = 0,
     interpret: Optional[bool] = None,
+    scale: Optional[float] = None,   # None -> d ** -0.5
 ) -> jnp.ndarray:
     if impl == "naive":
-        return mha_reference(q, k, v, causal=causal, q_offset=q_offset)
+        return mha_reference(q, k, v, causal=causal, scale=scale,
+                             q_offset=q_offset)
     if impl == "chunked":
         return chunked_attention(
-            q, k, v, causal=causal, chunk=min(chunk, k.shape[2]),
-            q_offset=q_offset,
+            q, k, v, causal=causal, scale=scale,
+            chunk=min(chunk, k.shape[2]), q_offset=q_offset,
         )
+    if scale is not None:
+        raise ValueError("the Pallas kernel scales by d ** -0.5 only")
     if impl == "pallas":
         b, h, sq, d = q.shape
         kvh = k.shape[1]

@@ -1,5 +1,6 @@
 """Attention layer: GQA/MQA self-attention (causal or full), cross
-attention, RoPE variants, KV-cache decode.  Projections route through
+attention, RoPE variants or none (NoPE), a configured score scale,
+KV-cache decode.  Projections route through
 ``approx_linear.linear`` so the DSE policy applies."""
 
 from __future__ import annotations
@@ -42,6 +43,7 @@ def gqa_decode_attention(
     ck: jnp.ndarray,   # (b, kvh, S, d) — kv_seq sharded on "model"
     cv: jnp.ndarray,
     pos: jnp.ndarray,  # scalar: current position (attend to kpos <= pos)
+    scale: Optional[float] = None,  # None -> d ** -0.5
 ) -> jnp.ndarray:
     """Single-token decode attention, sharding-aware:
 
@@ -60,7 +62,7 @@ def gqa_decode_attention(
     qg = constrain(
         q.reshape(b, kvh, rep, d), ("batch", "kv_heads", None, None)
     )
-    scale = d ** -0.5
+    scale = d ** -0.5 if scale is None else scale
     scores = jnp.einsum(
         "bgrd,bgsd->bgrs", (qg * scale).astype(jnp.float32),
         ck.astype(jnp.float32),
@@ -110,10 +112,11 @@ def self_attention(
     q = constrain(q, ("batch", "act_heads", "seq", None))
     k = constrain(k, ("batch", "kv_heads", "seq", None))
 
-    if pos is not None:
-        positions = jnp.zeros((s,), jnp.int32) + pos  # decode: (1,)
-    q = apply_rope(q, inv_freq, positions)
-    k = apply_rope(k, inv_freq, positions)
+    if cfg.rope_style != "none":
+        if pos is not None:
+            positions = jnp.zeros((s,), jnp.int32) + pos  # decode: (1,)
+        q = apply_rope(q, inv_freq, positions)
+        k = apply_rope(k, inv_freq, positions)
 
     new_cache = None
     if cache is not None:
@@ -134,10 +137,11 @@ def self_attention(
 
     if pos is not None:
         # decode: dedicated sharding-aware single-token attention
-        out = gqa_decode_attention(q, k, v, pos)
+        out = gqa_decode_attention(q, k, v, pos, scale=cfg.attn_scale)
     else:
         out = attn_op(
             q, k, v, causal=causal, impl="chunked", chunk=attn_chunk,
+            scale=cfg.attn_scale,
         )
     out = constrain(out, ("batch", "act_heads", "seq", None))
     y = linear(_merge_heads(out), p["wo"], "attn_out", policy)

@@ -40,7 +40,7 @@ class ParamSpec:
     shape: Tuple[int, ...]
     logical: Tuple[Optional[str], ...]
     dtype: str = "float32"
-    init: str = "normal"      # normal | zeros | ones | conv
+    init: str = "normal"      # normal | zeros | ones | uniform | a_log | dt_bias
     scale: float = 0.02
 
     def __post_init__(self):
@@ -49,6 +49,29 @@ class ParamSpec:
 
 def _is_spec(x) -> bool:
     return isinstance(x, ParamSpec)
+
+
+def _uniform(key, shape, scale):
+    """U(-scale, scale): PyTorch's default for a depthwise conv, whose
+    fan-in is its kernel length (scale = k ** -0.5)."""
+    return jax.random.uniform(key, shape, jnp.float32, -scale, scale)
+
+
+def _a_log(key, shape, scale):
+    """Mamba-2: A = -exp(A_log) with A ~ U(1, 16) per head."""
+    return jnp.log(jax.random.uniform(key, shape, jnp.float32, 1.0, 16.0))
+
+
+def _dt_bias(key, shape, scale):
+    """Mamba-2: softplus(dt_bias) = dt, log-uniform on [1e-3, 1e-1] and
+    floored at 1e-4; the bias is softplus's inverse of dt."""
+    u = jax.random.uniform(key, shape, jnp.float32)
+    dt = jnp.maximum(jnp.exp(u * (jnp.log(0.1) - jnp.log(1e-3))
+                             + jnp.log(1e-3)), 1e-4)
+    return dt + jnp.log(-jnp.expm1(-dt))
+
+
+_INITS = {"uniform": _uniform, "a_log": _a_log, "dt_bias": _dt_bias}
 
 
 def init_tree(specs, key: jax.Array):
@@ -61,6 +84,9 @@ def init_tree(specs, key: jax.Array):
             arr = jnp.zeros(spec.shape, spec.dtype)
         elif spec.init == "ones":
             arr = jnp.ones(spec.shape, spec.dtype)
+        elif spec.init in _INITS:
+            arr = _INITS[spec.init](k, spec.shape, spec.scale)
+            arr = arr.astype(spec.dtype)
         else:
             arr = (
                 jax.random.normal(k, spec.shape, jnp.float32) * spec.scale

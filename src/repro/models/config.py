@@ -39,17 +39,32 @@ class ModelConfig:
     tie_embeddings: bool = False
     # --- rope ---
     rope_theta: float = 10000.0
-    rope_style: str = "standard"              # standard | half (chatglm 2d) | mrope
+    rope_style: str = "standard"              # standard | half (chatglm 2d) | mrope | none (NoPE)
+    attn_scale: Optional[float] = None        # score scale; None -> head_dim ** -0.5
+    # --- residual-stream multipliers (granite); 1.0 adds no op ---
+    embedding_multiplier: float = 1.0         # embeddings scaled in bfloat16
+    residual_multiplier: float = 1.0          # x + r * sublayer(x)
+    logits_scaling: float = 1.0               # logits divided by this
     # --- MoE ---
     n_experts: int = 0
     n_experts_active: int = 0
     moe_period: int = 1                       # MoE every `moe_period` layers
     capacity_factor: float = 1.25
+    moe_dispatch: str = "capacity"            # capacity (GShard) | dropless
+    held_experts: Optional[Tuple[int, int]] = None  # [first, stop) held here; None -> all
+    shared_ff: int = 0                        # width of a shared SwiGLU expert; 0 -> none
     # --- SSM (mamba1) ---
     ssm_state: int = 0
     ssm_conv: int = 4
     ssm_expand: int = 2
     dt_rank: int = 0                          # default ceil(d_model/16)
+    # --- SSM (mamba2 / SSD) ---
+    ssm_kind: str = "mamba1"                  # mamba1 | mamba2
+    ssm_heads: int = 0                        # mamba2: heads; d_inner = heads * head_dim
+    ssm_head_dim: int = 0
+    ssm_groups: int = 1                       # mamba2: groups sharing B and C
+    ssm_chunk: int = 256                      # mamba2: SSD chunk length in prefill
+    ssm_state_dtype: str = "float32"          # mamba2: the decode cache's state
     attn_period: int = 0                      # hybrid: 1 attn per N layers
     attn_offset: int = 4                      # position of attn in the block
     # --- enc-dec ---
@@ -85,6 +100,16 @@ class ModelConfig:
         return self.ssm_expand * self.d_model
 
     @property
+    def held_range(self) -> Tuple[int, int]:
+        """[first, stop) of the routed experts this chip holds."""
+        return self.held_experts or (0, self.n_experts)
+
+    @property
+    def n_held(self) -> int:
+        lo, hi = self.held_range
+        return hi - lo
+
+    @property
     def padded_vocab(self) -> int:
         return -(-self.vocab_size // 256) * 256
 
@@ -93,7 +118,11 @@ class ModelConfig:
         """Experts padded to 16-way EP divisibility (e.g. granite-moe
         40 -> 48; padded experts are masked out of routing).  Small expert
         counts (<=16) are left unpadded and replicate under the fallback
-        rule when they don't divide the model axis."""
+        rule when they don't divide the model axis.  Dropless routing
+        pads nothing: it holds ``n_held`` experts and routes over
+        ``n_experts``."""
+        if self.moe_dispatch == "dropless":
+            return self.n_held
         if self.n_experts > 16:
             return -(-self.n_experts // 16) * 16
         return self.n_experts
@@ -152,6 +181,13 @@ class ModelConfig:
             n = self.n_superblocks
             if kind.mixer == "attn":
                 total += n * d * hd * (self.n_heads * 2 + self.n_kv_heads * 2)
+            elif self.ssm_kind == "mamba2":
+                di, st, hh = self.d_inner, self.ssm_state, self.ssm_heads
+                conv = di + 2 * self.ssm_groups * st
+                total += n * (
+                    d * (di + conv + hh) + (self.ssm_conv + 1) * conv
+                    + 3 * hh + di + di * d
+                )
             else:
                 di, st, dtr = self.d_inner, self.ssm_state, self.resolved_dt_rank
                 total += n * (
@@ -163,7 +199,8 @@ class ModelConfig:
             if kind.mlp == "dense":
                 total += n * 3 * d * ff
             elif kind.mlp == "moe":
-                total += n * (self.n_experts * 3 * d * ff + d * self.n_experts)
+                total += n * (self.n_experts * 3 * d * ff + d * self.n_experts
+                              + 3 * d * self.shared_ff)
         if self.is_encoder_decoder:
             # encoder layers mirror the decoder's self-attn + mlp
             total += self.n_enc_layers * (
@@ -206,5 +243,17 @@ def reduced(cfg: ModelConfig, **overrides) -> ModelConfig:
         frontend_len=min(cfg.frontend_len, 8) if cfg.frontend_len else 0,
         name=cfg.name + "-smoke",
     )
+    if cfg.ssm_kind == "mamba2":
+        # d_inner = expand * 64 = 128 as 8 heads of 16; chunks of 8 so
+        # that a smoke prompt spans several
+        defaults.update(ssm_heads=8, ssm_head_dim=16, ssm_chunk=8)
+    if cfg.shared_ff:
+        defaults.update(shared_ff=96)
+    if cfg.held_experts is not None:
+        defaults.update(held_experts=None)
     defaults.update(overrides)
+    if cfg.embedding_multiplier == cfg.d_model ** 0.5:
+        # a multiplier of sqrt(d_model) (gemma) follows the cut width
+        defaults.setdefault("embedding_multiplier",
+                            defaults["d_model"] ** 0.5)
     return replace(cfg, **defaults)

@@ -26,7 +26,14 @@ from .attention import (
 from .common import ParamSpec, make_rope, rms_norm
 from .config import LayerKind, ModelConfig
 from .moe import dense_mlp, dense_mlp_param_specs, moe_layer, moe_param_specs
-from .ssm import mamba_cache_spec, mamba_layer, mamba_param_specs
+from .ssm import (
+    mamba2_cache_spec,
+    mamba2_layer,
+    mamba2_param_specs,
+    mamba_cache_spec,
+    mamba_layer,
+    mamba_param_specs,
+)
 
 __all__ = [
     "param_specs",
@@ -45,6 +52,8 @@ def _layer_specs(cfg: ModelConfig, kind: LayerKind) -> Dict[str, Any]:
     out: Dict[str, Any] = {}
     if kind.mixer == "attn":
         out["attn"] = attn_param_specs(cfg)
+    elif cfg.ssm_kind == "mamba2":
+        out["mamba"] = mamba2_param_specs(cfg)
     else:
         out["mamba"] = mamba_param_specs(cfg)
     if kind.cross_attn:
@@ -100,8 +109,14 @@ def cache_specs(
         c: Dict[str, Any] = {}
         if kind.mixer == "attn":
             c["kv"] = init_kv_cache_spec(cfg, batch, max_len)
+        elif cfg.ssm_kind == "mamba2":
+            c["ssm_state"] = mamba2_cache_spec(cfg, batch)
         else:
             c["ssm_state"] = mamba_cache_spec(cfg, batch)
+        if kind.mlp == "moe" and cfg.moe_dispatch == "dropless":
+            # running count of the routing picks that land on the held
+            # experts (Generator reads it; see moe_layer)
+            c["held_picks"] = ParamSpec((), (), dtype="int32", init="zeros")
         if kind.cross_attn:
             hd = cfg.resolved_head_dim
             c["cross"] = {
@@ -144,6 +159,11 @@ def _superblock(
         # during backward; nesting bounds the live set to one layer
         return jax.checkpoint(fn) if training else fn
 
+    def residual(x, y):
+        if cfg.residual_multiplier != 1.0:
+            y = y * jnp.asarray(cfg.residual_multiplier, y.dtype)
+        return x + y
+
     for i, kind in enumerate(cfg.block_pattern):
         lp = blk[f"layer{i}"]
         ch = caches[f"layer{i}"] if caches is not None else None
@@ -155,22 +175,27 @@ def _superblock(
                     cache=ch["kv"] if ch is not None else None, pos=pos,
                     attn_chunk=attn_chunk,
                 )
-            y, kv = ckpt(attn_fn)(lp["attn"], x)
+            with jax.named_scope("attn"):
+                y, kv = ckpt(attn_fn)(lp["attn"], x)
             if kv is not None:
                 nch["kv"] = kv
-            x = x + y
+            x = residual(x, y)
         else:
             def mamba_fn(lp_, x_):
+                cache = ch["ssm_state"] if ch is not None else None
+                if cfg.ssm_kind == "mamba2":
+                    return mamba2_layer(lp_, x_, cfg, policy=policy,
+                                        cache=cache, decode=pos is not None)
                 return mamba_layer(
-                    lp_, x_, cfg, policy=policy,
-                    cache=ch["ssm_state"] if ch is not None else None,
+                    lp_, x_, cfg, policy=policy, cache=cache,
                     decode=pos is not None,
                     scan_chunk=scan_chunk,
                 )
-            y, sc = ckpt(mamba_fn)(lp["mamba"], x)
+            with jax.named_scope(cfg.ssm_kind):
+                y, sc = ckpt(mamba_fn)(lp["mamba"], x)
             if sc is not None:
                 nch["ssm_state"] = sc
-            x = x + y
+            x = residual(x, y)
         if kind.cross_attn:
             cached = ch["cross"] if (ch is not None and pos is not None) else None
             y, ckv = cross_attention(
@@ -189,9 +214,11 @@ def _superblock(
         elif kind.mlp == "moe":
             def moe_fn(lp_, x_):
                 return moe_layer(lp_, x_, cfg, policy=policy)
-            y, a = ckpt(moe_fn)(lp["moe"], x)
-            x = x + y
+            y, a, picks = ckpt(moe_fn)(lp["moe"], x)
+            x = residual(x, y)
             aux = aux + a
+            if ch is not None and "held_picks" in ch:
+                nch["held_picks"] = ch["held_picks"] + picks
         x = constrain(x, ("batch", "seq", "act_embed"))
         new_caches[f"layer{i}"] = nch
     return x, (new_caches if caches is not None else None), aux
@@ -206,6 +233,14 @@ def _embed(params, cfg: ModelConfig, tokens: jnp.ndarray) -> jnp.ndarray:
     return x
 
 
+def scale_embeddings(cfg: ModelConfig, x: jnp.ndarray) -> jnp.ndarray:
+    """The configured embedding multiplier, applied in the embeddings'
+    own type (bfloat16); none where it is 1."""
+    if cfg.embedding_multiplier == 1.0:
+        return x
+    return x * jnp.asarray(cfg.embedding_multiplier, x.dtype)
+
+
 def _logits(params, cfg: ModelConfig, x: jnp.ndarray) -> jnp.ndarray:
     x = rms_norm(x, params["final_norm"], cfg.rms_eps)
     head = (
@@ -214,6 +249,8 @@ def _logits(params, cfg: ModelConfig, x: jnp.ndarray) -> jnp.ndarray:
     logits = jnp.einsum(
         "bsd,dv->bsv", x.astype(jnp.bfloat16), head.astype(jnp.bfloat16)
     )
+    if cfg.logits_scaling != 1.0:
+        logits = logits / jnp.asarray(cfg.logits_scaling, logits.dtype)
     return constrain(logits, ("batch", "seq", "vocab"))
 
 
@@ -327,8 +364,7 @@ def forward(
     if tokens is not None:
         parts.append(_embed(params, cfg, tokens))
     x = parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=1)
-    if cfg.name.startswith("gemma"):
-        x = x * jnp.asarray(cfg.d_model ** 0.5, x.dtype)
+    x = scale_embeddings(cfg, x)
     x = constrain(x, ("batch", "seq", "act_embed"))
 
     enc_out = None
@@ -359,9 +395,7 @@ def decode_step(
         make_rope(cfg.resolved_head_dim, cfg.rope_theta,
                   fraction=0.5 if cfg.rope_style == "half" else 1.0)
     )
-    x = _embed(params, cfg, tokens)
-    if cfg.name.startswith("gemma"):
-        x = x * jnp.asarray(cfg.d_model ** 0.5, x.dtype)
+    x = scale_embeddings(cfg, _embed(params, cfg, tokens))
     x, new_caches, _ = _scan_blocks(
         params, cfg, x, inv_freq, policy=policy, causal=True,
         caches=caches, pos=pos, enc_out=enc_out, remat=False,

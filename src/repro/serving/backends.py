@@ -108,9 +108,23 @@ class LMBackend:
         self.max_generators = int(max_generators)
         self._gens: "OrderedDict[bytes, object]" = OrderedDict()
         self._lock = threading.Lock()
+        self._routing = {"held_picks": 0, "decode_steps": 0}
 
     def group_key(self, req) -> Tuple:
         return (tuple(np.shape(req.inputs)), int(req.gen or 0))
+
+    def stats(self) -> Dict:
+        """Routing counters over every group run (dropless MoE configs):
+        ``held_picks``, the (token, expert) picks of decode steps that
+        landed on the experts held here, summed over the MoE layers;
+        ``decode_steps``, the decode steps of the groups that counted
+        them; and the shapes to read them against."""
+        cfg = self.accel.cfg
+        moe_layers = sum(k.mlp == "moe" for k in cfg.block_pattern) \
+            * cfg.n_superblocks
+        with self._lock:
+            return {**self._routing, "moe_layers": moe_layers,
+                    "held_experts": cfg.n_held if cfg.n_experts else 0}
 
     def _generator(self, point: OperatingPoint):
         from ..train.serve import Generator
@@ -145,6 +159,10 @@ class LMBackend:
         gen = self._generator(point)
         params = self.accel._ensure_params()
         tokens, tps = gen.generate(params, prompts, n_gen)
+        tokens = np.asarray(tokens)  # one read for the whole group
+        with self._lock:
+            for k in self._routing:
+                self._routing[k] += gen.last_routing[k]
         results = []
         for i, r in enumerate(reqs):
             res = {
@@ -156,9 +174,9 @@ class LMBackend:
                 "n_generated": n_gen,
             }
             if r.return_outputs:
-                res["tokens"] = np.asarray(tokens[i]).tolist()
+                res["tokens"] = tokens[i].tolist()
             else:
-                res["tokens"] = np.asarray(tokens[i, -n_gen:]).tolist()
+                res["tokens"] = tokens[i, -n_gen:].tolist()
             results.append(res)
         return results
 

@@ -22,6 +22,7 @@ from ..models.transformer import (
     _logits,
     _scan_blocks,
     encode,
+    scale_embeddings,
 )
 from ..models.common import make_rope
 
@@ -45,8 +46,7 @@ def make_prefill_step(cfg: ModelConfig, *, policy: Optional[ApproxPolicy] = None
         if batch.get("tokens") is not None:
             parts.append(_embed(params, cfg, batch["tokens"]))
         x = parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=1)
-        if cfg.name.startswith("gemma"):
-            x = x * jnp.asarray(cfg.d_model ** 0.5, x.dtype)
+        x = scale_embeddings(cfg, x)
         enc_out = None
         if cfg.is_encoder_decoder:
             enc_out = encode(params, cfg, batch["enc_embeds"],
@@ -78,6 +78,31 @@ def make_decode_step(cfg: ModelConfig, *, policy: Optional[ApproxPolicy] = None,
     return serve_step
 
 
+def state_bytes(caches) -> Dict[str, int]:
+    """Bytes of the decode caches by kind: the attention layers' key/value
+    cache and the SSM layers' state (recurrent state and conv window)."""
+    out = {"kv_cache_bytes": 0, "ssm_state_bytes": 0}
+    for layer in caches.values():
+        for kind, name in (("kv", "kv_cache_bytes"),
+                           ("ssm_state", "ssm_state_bytes")):
+            out[name] += sum(int(a.nbytes)
+                             for a in jax.tree.leaves(layer.get(kind, {})))
+    return out
+
+
+@jax.jit
+def _picks_total(counters):
+    return sum(jnp.sum(a) for a in counters)
+
+
+def _held_picks(caches) -> Optional[jax.Array]:
+    """Total of the MoE layers' held-expert pick counters as one device
+    scalar (no read), or None for a config that keeps none."""
+    leaves = [layer["held_picks"] for layer in caches.values()
+              if "held_picks" in layer]
+    return _picks_total(leaves) if leaves else None
+
+
 class Generator:
     """One (config, policy) pair's jitted prefill + decode steps, reused
     across prompt batches.
@@ -88,18 +113,30 @@ class Generator:
     ``launch.serve`` drives the same object for one-shot CLI runs.
     Caches are rebuilt per ``generate`` call — they are shape-keyed by
     (batch, prompt_len + gen), so distinct request shapes simply retrace
-    the two steps once each."""
+    the two steps once each.  Where the model holds a recurrent (SSM)
+    state, both steps take the caches donated: every step rewrites that
+    state whole, and without donation each step would allocate a fresh
+    copy of every cache while the device still reads the last one."""
 
     def __init__(self, cfg: ModelConfig, *,
                  policy: Optional[ApproxPolicy] = None,
                  attn_chunk: int = 1024, scan_chunk: int = 128):
         self.cfg = cfg
         self.policy = policy
+        donate = (("caches",) if any(k.mixer == "mamba"
+                                     for k in cfg.block_pattern) else ())
         self.prefill = jax.jit(make_prefill_step(
             cfg, policy=policy, attn_chunk=attn_chunk,
-            scan_chunk=scan_chunk))
-        self.decode = jax.jit(make_decode_step(cfg, policy=policy))
+            scan_chunk=scan_chunk), donate_argnames=donate)
+        self.decode = jax.jit(make_decode_step(cfg, policy=policy),
+                              donate_argnames=donate)
         self._tree_bytes: Dict[int, int] = {}
+        # one jitted maker of zeroed caches per cache shape
+        self._cache_makers: Dict[Tuple[int, int, int], Any] = {}
+        # routing picks that landed on the held experts in the decode
+        # steps of the last ``generate`` (dropless MoE configs), and the
+        # number of those steps
+        self.last_routing = {"held_picks": 0, "decode_steps": 0}
 
     def weight_bytes(self, params) -> int:
         """Bytes of the parameter tree, summed once per tree: the entry is
@@ -113,6 +150,20 @@ class Generator:
             if leaves:
                 weakref.finalize(leaves[0], self._tree_bytes.pop, key, None)
         return n
+
+    def new_caches(self, batch: int, max_len: int, enc_len: int, key):
+        """The decode caches for one ``generate`` call, made in one device
+        program."""
+        from ..models.common import init_tree
+        from ..models.transformer import cache_specs
+
+        shape = (batch, max_len, enc_len)
+        make = self._cache_makers.get(shape)
+        if make is None:
+            specs = cache_specs(self.cfg, batch, max_len, enc_len=enc_len)
+            make = self._cache_makers[shape] = jax.jit(
+                lambda k: init_tree(specs, k))
+        return make(key)
 
     def generate(
         self,
@@ -131,9 +182,6 @@ class Generator:
         bytes of the weights that served (``weight_bytes``)."""
         import time
 
-        from ..models.common import init_tree
-        from ..models.transformer import cache_specs
-
         cfg = self.cfg
         if key is None:
             key = jax.random.PRNGKey(0)
@@ -142,8 +190,7 @@ class Generator:
         vis = cfg.frontend_len if cfg.frontend == "vision" else 0
         max_len = prompt_len + int(gen) + vis
         enc_len = 16 if cfg.is_encoder_decoder else 0
-        caches = init_tree(
-            cache_specs(cfg, batch, max_len, enc_len=enc_len), key)
+        caches = self.new_caches(batch, max_len, enc_len, key)
 
         batch_in: Dict[str, Any] = {"tokens": prompts}
         if cfg.is_encoder_decoder:
@@ -155,7 +202,8 @@ class Generator:
                 jnp.float32) * 0.1
 
         with obs.span("lm.prefill", batch=batch, prompt_len=prompt_len,
-                      weight_bytes=self.weight_bytes(params)):
+                      weight_bytes=self.weight_bytes(params),
+                      **state_bytes(caches)):
             out = self.prefill(params, batch_in, caches)
             enc_out = None
             if cfg.is_encoder_decoder:
@@ -164,6 +212,7 @@ class Generator:
                 logits, caches = out
             nxt = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)[:, None]
             nxt.block_until_ready()
+        picks0 = _held_picks(caches)
 
         toks = [prompts, nxt]
         pos0 = prompt_len + vis
@@ -178,6 +227,10 @@ class Generator:
                 toks.append(nxt)
             nxt.block_until_ready()
         dt = time.perf_counter() - t0
+        if picks0 is not None:
+            before, after = jax.device_get((picks0, _held_picks(caches)))
+            self.last_routing = {"held_picks": int(after) - int(before),
+                                 "decode_steps": int(gen) - 1}
         tokens = jnp.concatenate(toks, axis=1)
         tps = batch * (int(gen) - 1) / max(dt, 1e-9)
         return tokens, tps

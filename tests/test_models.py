@@ -183,11 +183,31 @@ def test_moe_grouping_equivalence():
     prev = moe_mod.MOE_GROUP
     try:
         moe_mod.set_moe_group(0)
-        y0, a0 = moe_layer(p, x, cfg)
+        y0, a0, _ = moe_layer(p, x, cfg)
         moe_mod.set_moe_group(8)   # 4 groups of 8 tokens
-        y1, a1 = moe_layer(p, x, cfg)
+        y1, a1, _ = moe_layer(p, x, cfg)
     finally:
         moe_mod.set_moe_group(prev)
     np.testing.assert_allclose(np.asarray(y0, np.float32),
                                np.asarray(y1, np.float32),
                                rtol=2e-2, atol=2e-2)
+
+
+@pytest.mark.parametrize("overrides", [None, {}, {"d_model": 32}],
+                         ids=["published", "reduced", "reduced-d32"])
+def test_gemma_embedding_scale_is_sqrt_d_model(overrides):
+    """gemma scales its embeddings by sqrt(d_model) in bfloat16, at the
+    published width and at every cut width ``reduced`` gives it."""
+    from repro.models.transformer import scale_embeddings
+
+    cfg = get_config("gemma-2b")
+    if overrides is not None:
+        cfg = reduced(cfg, **overrides)
+    x = jax.random.normal(KEY, (2, 3, cfg.d_model)).astype(jnp.bfloat16)
+    want = x * jnp.asarray(cfg.d_model ** 0.5, x.dtype)
+    np.testing.assert_array_equal(np.asarray(scale_embeddings(cfg, x)),
+                                  np.asarray(want))
+
+
+def test_reduced_keeps_a_fixed_embedding_multiplier():
+    assert reduced(get_config("granite-4.0-h-small")).embedding_multiplier == 12.0

@@ -5,9 +5,12 @@ a described topology: what it refuses here (an op Mosaic does not lower,
 a program that does not fit) would be refused on the chip.  Nothing runs.
 Covered: the fused population-sim programs of the image families (sim,
 and the int64 SSE tail where the plan has one) at two population
-buckets, the approximate projection at granite-8b FFN width, and one
-granite-8b decode step at published widths.  Each case prints its
-compile seconds and ``memory_analysis`` (``pytest -s`` shows them).
+buckets, the approximate projection at granite-8b FFN width, one
+granite-8b decode step at published widths, granite-8b's serving
+programs against the ones recorded before the hybrid layers came (op
+metadata aside), and granite-4.0-h-small's prefill and decode step at
+its benchmark cell's shapes.  Each case prints its compile seconds and
+``memory_analysis`` (``pytest -s`` shows them).
 
 The topology is described inside a module fixture, never at import: only
 one process may load the TPU library at a time, and every test worker
@@ -15,6 +18,10 @@ imports this file.
 """
 
 import dataclasses
+import hashlib
+import json
+import os
+import re
 import time
 
 import numpy as np
@@ -149,3 +156,119 @@ def test_granite_8b_decode_step_compiles_for_v5e(one_chip):
     print(f"granite-8b decode step, 1 layer: compiled in "
           f"{time.perf_counter() - t0:.3f} s; {_bytes(mem)}")
     assert mem.argument_size_in_bytes > cfg.padded_vocab * cfg.d_model * 4
+
+
+def _on_chip(tree, one_chip):
+    return jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        tree)
+
+
+def _canonical_hlo(text: str) -> str:
+    """Optimized HLO without what names and locates ops: the debug tables
+    of file and function names, each op's ``metadata`` and the numbers
+    in instruction names (renamed in order of first use)."""
+    out, skip = [], False
+    for ln in text.splitlines():
+        if ln in ("FileNames", "FunctionNames", "FileLocations", "StackFrames"):
+            skip = True
+            continue
+        if skip and (ln.startswith("%") or ln.startswith("ENTRY")):
+            skip = False
+        if not skip:
+            out.append(ln)
+    text = re.sub(r",? metadata=\{[^}]*\}", "", "\n".join(out))
+    names = {}
+    return re.sub(r"%[\w.\-]+",
+                  lambda m: names.setdefault(m.group(0), f"%v{len(names)}"), text)
+
+
+# sha256 of ``_canonical_hlo`` of granite-8b's serving programs (1 layer at
+# published widths, the LM cell's shapes) as compiled before the Mamba-2,
+# dropless-MoE and granite-multiplier paths were added, with this JAX
+# (0.9.0) and its TPU compiler
+GRANITE_8B_HLO = {
+    "jit_prefill": "87251310b746ff14f0d875977c83714fb843fbbc59be24dffd0888f77eeed813",
+    "jit_serve_step": "7e3f0601b46b964ccf3ea227b67ee4cfcb71963eb8133159c301298bee771f5c",
+}
+
+
+def test_granite_8b_serving_programs_are_unchanged(one_chip):
+    """The options the hybrid configuration brings default to no op, and
+    the ones that are 1.0 add no multiply: granite-8b compiles to the same
+    optimized prefill and decode step as before them."""
+    from repro.configs import get_config
+    from repro.models.common import init_tree
+    from repro.models.transformer import cache_specs, param_specs
+    from repro.train.serve import Generator
+
+    if jax.__version__ != "0.9.0":
+        pytest.skip(f"digests were recorded with JAX 0.9.0, not {jax.__version__}")
+    cfg = dataclasses.replace(get_config("granite-8b"), n_layers=1)
+    key = jax.random.PRNGKey(0)
+    params = _on_chip(jax.eval_shape(lambda: init_tree(param_specs(cfg), key)),
+                      one_chip)
+    caches = _on_chip(jax.eval_shape(
+        lambda: init_tree(cache_specs(cfg, 8, 1152), key)), one_chip)
+    prompt = jax.ShapeDtypeStruct((8, 1024), jnp.int32, sharding=one_chip)
+    token = jax.ShapeDtypeStruct((8, 1), jnp.int32, sharding=one_chip)
+    pos = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    gen = Generator(cfg, attn_chunk=32, scan_chunk=8)  # as LMBackend's
+    programs = {
+        "jit_prefill": (gen.prefill, (params, {"tokens": prompt}, caches)),
+        "jit_serve_step": (gen.decode, (params, caches, token, pos)),
+    }
+    for name, (fn, args) in programs.items():
+        text = fn.lower(*args).compile().as_text()
+        digest = hashlib.sha256(_canonical_hlo(text).encode()).hexdigest()
+        print(f"granite-8b {name}: {digest}")
+        assert digest == GRANITE_8B_HLO[name], name
+
+
+def test_granite_4_h_small_cell_programs_fit_one_v5e(one_chip):
+    """granite-4.0-h-small as its benchmark cell serves it (10 layers at
+    published widths, 9 held experts, groups of 32, 1024-token prompts and
+    128 generated): prefill and decode step compile for one v5e, and each
+    one's arguments, outputs and temporaries fit its 16 GB.  Both take
+    the caches donated; the decode step's outputs alias every cache
+    (prefill never reads the incoming conv windows, so jit drops them)."""
+    import sys
+
+    from repro.models.common import init_tree
+    from repro.models.transformer import cache_specs, param_specs
+    from repro.train.serve import Generator
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    sys.path.insert(0, os.path.join(root, "bench"))
+    from benchkit.drivers.lm_hybrid_closed import program_config
+
+    with open(os.path.join(root, "bench", "configs",
+                           "granite-4.0-h-small.json")) as f:
+        cfg = program_config(json.load(f))
+    key = jax.random.PRNGKey(0)
+    params = _on_chip(jax.eval_shape(lambda: init_tree(param_specs(cfg), key)),
+                      one_chip)
+    caches = _on_chip(jax.eval_shape(
+        lambda: init_tree(cache_specs(cfg, 32, 1152), key)), one_chip)
+    prompt = jax.ShapeDtypeStruct((32, 1024), jnp.int32, sharding=one_chip)
+    token = jax.ShapeDtypeStruct((32, 1), jnp.int32, sharding=one_chip)
+    pos = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    gen = Generator(cfg, attn_chunk=32, scan_chunk=8)  # as LMBackend's
+    cache_bytes = sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                      for a in jax.tree.leaves(caches))
+    programs = {
+        "jit_serve_step": (gen.decode, (params, caches, token, pos)),
+        "jit_prefill": (gen.prefill, (params, {"tokens": prompt}, caches)),
+    }
+    for name, (fn, args) in programs.items():
+        t0 = time.perf_counter()
+        mem = fn.lower(*args).compile().memory_analysis()
+        total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+                 + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+        print(f"granite-4.0-h-small {name}, batch 32: compiled in "
+              f"{time.perf_counter() - t0:.3f} s; {_bytes(mem)}; "
+              f"aliased {mem.alias_size_in_bytes} B; {total} B in all")
+        assert mem.argument_size_in_bytes > 4.8e9  # the bfloat16 weights
+        if name == "jit_serve_step":
+            assert mem.alias_size_in_bytes >= cache_bytes  # padded tiles
+        assert total < 16e9, name
