@@ -271,11 +271,6 @@ def _scan_blocks(params, cfg, x, inv_freq, *, policy, causal, caches, pos,
     }
 
     def body_fn(blk, x, caches=None):
-        # barrier: stops XLA hoisting per-layer weight transforms (e.g.
-        # the CPU backend's bf16->f32 dot upcast) out of the loop, which
-        # would materialize f32 copies of the ENTIRE stacked stack at
-        # once (observed +20 GB on the 398B config)
-        blk = jax.lax.optimization_barrier(blk)
         if remat:
             blk = jax.tree.map(
                 lambda t, s: constrain_cotangent(t, s.logical),

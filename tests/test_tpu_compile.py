@@ -7,9 +7,11 @@ Covered: the fused population-sim programs of the image families (sim,
 and the int64 SSE tail where the plan has one) at two population
 buckets, the approximate projection at granite-8b FFN width, one
 granite-8b decode step at published widths, granite-8b's serving
-programs against the ones recorded before the hybrid layers came (op
-metadata aside), and granite-4.0-h-small's prefill and decode step at
-its benchmark cell's shapes.  Each case prints its compile seconds and
+programs against recorded digests (op metadata aside), granite-8b's
+layer scan at its benchmark cell's shapes (no weight copied out of the
+stack, temporaries bounded, exact and approximate), and
+granite-4.0-h-small's prefill and decode step at its benchmark cell's
+shapes.  Each case prints its compile seconds and
 ``memory_analysis`` (``pytest -s`` shows them).
 
 The topology is described inside a module fixture, never at import: only
@@ -183,28 +185,17 @@ def _canonical_hlo(text: str) -> str:
                   lambda m: names.setdefault(m.group(0), f"%v{len(names)}"), text)
 
 
-# sha256 of ``_canonical_hlo`` of granite-8b's serving programs (1 layer at
-# published widths, the LM cell's shapes) as compiled before the Mamba-2,
-# dropless-MoE and granite-multiplier paths were added, with this JAX
-# (0.9.0) and its TPU compiler
-GRANITE_8B_HLO = {
-    "jit_prefill": "87251310b746ff14f0d875977c83714fb843fbbc59be24dffd0888f77eeed813",
-    "jit_serve_step": "7e3f0601b46b964ccf3ea227b67ee4cfcb71963eb8133159c301298bee771f5c",
-}
-
-
-def test_granite_8b_serving_programs_are_unchanged(one_chip):
-    """The options the hybrid configuration brings default to no op, and
-    the ones that are 1.0 add no multiply: granite-8b compiles to the same
-    optimized prefill and decode step as before them."""
+def _granite_8b_programs(one_chip, n_layers, policy=None):
+    """granite-8b's serving programs as the LM cell builds them, at
+    published widths and ``n_layers`` layers: name -> (jitted, args) of
+    the prefill of a group of 8 1024-token prompts and one decode step
+    against caches of 1152."""
     from repro.configs import get_config
     from repro.models.common import init_tree
     from repro.models.transformer import cache_specs, param_specs
     from repro.train.serve import Generator
 
-    if jax.__version__ != "0.9.0":
-        pytest.skip(f"digests were recorded with JAX 0.9.0, not {jax.__version__}")
-    cfg = dataclasses.replace(get_config("granite-8b"), n_layers=1)
+    cfg = dataclasses.replace(get_config("granite-8b"), n_layers=n_layers)
     key = jax.random.PRNGKey(0)
     params = _on_chip(jax.eval_shape(lambda: init_tree(param_specs(cfg), key)),
                       one_chip)
@@ -213,16 +204,102 @@ def test_granite_8b_serving_programs_are_unchanged(one_chip):
     prompt = jax.ShapeDtypeStruct((8, 1024), jnp.int32, sharding=one_chip)
     token = jax.ShapeDtypeStruct((8, 1), jnp.int32, sharding=one_chip)
     pos = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
-    gen = Generator(cfg, attn_chunk=32, scan_chunk=8)  # as LMBackend's
-    programs = {
+    gen = Generator(cfg, policy=policy, attn_chunk=32, scan_chunk=8)  # as LMBackend's
+    return {
         "jit_prefill": (gen.prefill, (params, {"tokens": prompt}, caches)),
         "jit_serve_step": (gen.decode, (params, caches, token, pos)),
     }
-    for name, (fn, args) in programs.items():
+
+
+# sha256 of ``_canonical_hlo`` of granite-8b's serving programs (1 layer at
+# published widths, the LM cell's shapes) as compiled once the layer scan
+# read each layer's weights straight from the stack (no barrier on them),
+# with this JAX (0.9.0) and its TPU compiler
+GRANITE_8B_HLO = {
+    "jit_prefill": "8ee7c46855a74f4f1925a2b9d4d813a94cfeb4e5426f729636815bd847cf213b",
+    "jit_serve_step": "dc3c565f2a3b3aeff527c5b54e8cfc57c73913bd7f41fcf00d5baae06e3e06d0",
+}
+
+
+def test_granite_8b_serving_programs_are_unchanged(one_chip):
+    """granite-8b compiles to the recorded optimized prefill and decode
+    step: a change to what the model step computes for the dense LM cell
+    shows here before it shows on the chip."""
+    if jax.__version__ != "0.9.0":
+        pytest.skip(f"digests were recorded with JAX 0.9.0, not {jax.__version__}")
+    for name, (fn, args) in _granite_8b_programs(one_chip, 1).items():
         text = fn.lower(*args).compile().as_text()
         digest = hashlib.sha256(_canonical_hlo(text).encode()).hexdigest()
         print(f"granite-8b {name}: {digest}")
         assert digest == GRANITE_8B_HLO[name], name
+
+
+def _computations(text: str) -> dict:
+    """Optimized HLO text -> {computation name: its instruction lines}."""
+    comps, name = {}, None
+    for ln in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+) .*\{$", ln)
+        if head:
+            name = head.group(1)
+            comps[name] = []
+        elif ln == "}":
+            name = None
+        elif name is not None:
+            comps[name].append(ln)
+    return comps
+
+
+def _weight_slice_copies(text: str, shapes) -> list:
+    """Scheduled fusions (not ones inlined into another fusion) that
+    dynamic-slice a result of one of ``shapes`` into a buffer of its own."""
+    comps = _computations(text)
+    fused = set(re.findall(r"calls=%([\w.\-]+)", text))
+    out = []
+    for comp, lines in comps.items():
+        if comp in fused:
+            continue
+        for ln in lines:
+            m = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = (\w+\[[\d,]*\])\S* "
+                         r"fusion\(.*calls=%([\w.\-]+)", ln)
+            if (m and m.group(2) in shapes
+                    and any(" dynamic-slice(" in c for c in comps[m.group(3)])):
+                out.append(f"{m.group(1)} {m.group(2)}")
+    return out
+
+
+GRANITE_8B_WEIGHTS = ("bf16[4096,14336]", "bf16[14336,4096]", "bf16[4096,4096]")
+
+
+@pytest.mark.parametrize("tier", ["exact", "approx"])
+def test_granite_8b_layer_scan_reads_weights_in_place(tier, one_chip):
+    """The layer scan of granite-8b at the LM cell's shapes (8 layers at
+    published widths, batch 8, caches 1152) reads each layer's weights
+    straight from the stacked tree: the decode step copies no weight
+    matrix out of the stack (a barrier on the layer's slice did, 353 MB
+    of temporaries and 0.83 GB more read a layer), and prefill's
+    temporaries stay under 600 MB (816 MB with the copies).  Under an
+    approximate policy, which quantizes ``w`` on every call, no transform
+    of the whole stack is hoisted out of the loop either: the decode
+    step's temporaries stay under 600 MB (824 MB with the barrier; the
+    ``ffn_in`` stack alone is 1.9 GB in bfloat16)."""
+    from repro.models import ApproxPolicy
+
+    policy = (None if tier == "exact"
+              else ApproxPolicy({"ffn_in": ("mul8s_drum4", 2)}))
+    programs = _granite_8b_programs(one_chip, 8, policy=policy)
+    limits = {"exact": {"jit_serve_step": 64e6, "jit_prefill": 600e6},
+              "approx": {"jit_serve_step": 600e6}}[tier]
+    for name, limit in limits.items():
+        fn, args = programs[name]
+        t0 = time.perf_counter()
+        compiled = fn.lower(*args).compile()
+        mem = compiled.memory_analysis()
+        print(f"granite-8b {tier} {name}, 8 layers: compiled in "
+              f"{time.perf_counter() - t0:.3f} s; {_bytes(mem)}")
+        if name == "jit_serve_step":
+            copies = _weight_slice_copies(compiled.as_text(), GRANITE_8B_WEIGHTS)
+            assert not copies, copies
+        assert mem.temp_size_in_bytes < limit, (name, mem.temp_size_in_bytes)
 
 
 def test_granite_4_h_small_cell_programs_fit_one_v5e(one_chip):
